@@ -25,7 +25,9 @@ from typing import Any, Callable, Iterator, Optional, Sequence
 import jax
 import numpy as np
 
-_JAXPR_TYPES = (jax.core.ClosedJaxpr, jax.core.Jaxpr)
+from ..compat import ClosedJaxpr, Jaxpr
+
+_JAXPR_TYPES = (ClosedJaxpr, Jaxpr)
 
 #: primitives that put payload on the inter-device wire
 COLLECTIVE_PRIMS = frozenset(
@@ -33,19 +35,19 @@ COLLECTIVE_PRIMS = frozenset(
      "all_to_all"})
 
 
-def _sub_jaxprs(eqn) -> Iterator[jax.core.Jaxpr]:
+def _sub_jaxprs(eqn) -> Iterator[Jaxpr]:
     """The sub-jaxprs of one equation's params (scan/cond bodies, pjit /
     remat / custom_vjp calls), as plain Jaxprs."""
     for p in jax.tree.leaves(eqn.params,
                              is_leaf=lambda x: isinstance(x, _JAXPR_TYPES)):
-        if isinstance(p, jax.core.ClosedJaxpr):
+        if isinstance(p, ClosedJaxpr):
             yield p.jaxpr
-        elif isinstance(p, jax.core.Jaxpr):
+        elif isinstance(p, Jaxpr):
             yield p
 
 
-def _as_jaxpr(jaxpr) -> jax.core.Jaxpr:
-    return jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr
+def _as_jaxpr(jaxpr) -> Jaxpr:
+    return jaxpr.jaxpr if isinstance(jaxpr, ClosedJaxpr) else jaxpr
 
 
 def iter_eqns(jaxpr, *, skip_pallas: bool = True,

@@ -92,6 +92,8 @@ _VERSIONED = {
     "jax.experimental.mesh_utils": "repro.compat.make_mesh",
     "jax.experimental.pallas": "the kernels/ tier (backend-specific code)",
     "jax.experimental.maps": "repro.compat",
+    "jax.core": "repro.compat",
+    "jax.extend.core": "repro.compat.ClosedJaxpr / repro.compat.Jaxpr",
     "jax.tree_util.tree_map_with_path": "repro.compat.tree_map_with_path",
     "jax.tree_util.tree_flatten_with_path":
         "repro.compat.tree_flatten_with_path",

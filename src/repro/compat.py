@@ -1,57 +1,46 @@
 """JAX version-abstraction layer: the ONLY module allowed to touch
 version-specific JAX symbols.
 
-The runtime targets every JAX from 0.4.3x (installed here: 0.4.37, where
-``shard_map`` lives in ``jax.experimental.shard_map`` and takes
-``check_rep``) through current releases (``jax.shard_map`` with
-``check_vma``, meshes built with ``axis_types``).  Everything else in the
-repo imports these wrappers:
+The runtime targets JAX 0.9.0 (jaxlib 0.9.0; libtpu 0.0.34 on the TPU) and
+only that release.  Everything else in the repo imports these wrappers, so
+the next JAX upgrade edits this file and nothing else:
 
-  * ``shard_map(f, mesh=..., in_specs=..., out_specs=..., check=False)``
-  * ``make_mesh(axis_shapes, axis_names)`` -- tries the ``axis_types``
-    (explicit-sharding-era) API first, falls back to plain ``jax.make_mesh``
-    and finally to ``mesh_utils`` + ``Mesh``
-  * ``tree_flatten_with_path`` / ``tree_unflatten`` -- ``jax.tree`` grew
-    ``flatten_with_path`` after 0.4.37; older code spells it
-    ``jax.tree_util.tree_flatten_with_path``.  (Plain ``jax.tree.map`` /
-    ``leaves`` exist on every supported version and are used directly.)
+  * ``shard_map(f, mesh=..., in_specs=..., out_specs=..., check=False)`` --
+    ``jax.shard_map`` with ``check_vma``
+  * ``make_mesh(axis_shapes, axis_names)`` -- ``jax.make_mesh`` with every
+    axis ``AxisType.Auto`` (classic shard_map + NamedSharding semantics)
+  * ``ClosedJaxpr`` / ``Jaxpr`` -- the jaxpr types, from ``jax.extend.core``
+  * ``optimization_barrier`` -- a differentiable barrier over a pytree
+  * ``float8_dtypes()`` -- the float8 wire/store dtypes by format alias
+  * ``cost_analysis(compiled)`` -- ``Compiled.cost_analysis()`` as a dict
+  * ``tree_flatten_with_path`` / ``tree_unflatten`` / ``tree_map_with_path``
+    -- the ``jax.tree`` path utilities
 """
 from __future__ import annotations
 
 from typing import Any, Callable
 
 import jax
+import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
+
+__all__ = [
+    "ClosedJaxpr", "Jaxpr", "cost_analysis", "float8_dtypes", "make_mesh",
+    "optimization_barrier", "shard_map", "tree_flatten_with_path",
+    "tree_map_with_path", "tree_unflatten",
+]
+
 
 # --------------------------------------------------------------------------- #
 # shard_map
 # --------------------------------------------------------------------------- #
-_new_shard_map = getattr(jax, "shard_map", None)
-if _new_shard_map is None:
-    from jax.experimental.shard_map import shard_map as _impl_shard_map
-else:
-    _impl_shard_map = _new_shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma after the
-# top-level jax.shard_map export appeared, so key on the actual signature
-# rather than on where the function lives
-try:
-    import inspect as _inspect
-
-    _CHECK_KW = ("check_vma"
-                 if "check_vma" in _inspect.signature(
-                     _impl_shard_map).parameters
-                 else "check_rep")
-except (TypeError, ValueError):  # C-accelerated wrapper: assume current API
-    _CHECK_KW = "check_vma"
-
-
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check: bool = False) -> Callable:
-    """Portable shard_map.  ``check`` maps to ``check_vma`` on new JAX and
-    ``check_rep`` on old JAX (both default False here: the runtime uses
-    untraceable-replication collectives like psum_scatter)."""
-    return _impl_shard_map(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_CHECK_KW: check})
+    """``jax.shard_map``; ``check`` maps to ``check_vma`` (default False
+    here: the runtime uses untraceable-replication collectives like
+    psum_scatter)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 # --------------------------------------------------------------------------- #
@@ -59,41 +48,23 @@ def shard_map(f: Callable, *, mesh, in_specs, out_specs,
 # --------------------------------------------------------------------------- #
 def make_mesh(axis_shapes: tuple[int, ...], axis_names: tuple[str, ...],
               *, devices=None):
-    """Build a Mesh on any JAX version.
-
-    New JAX wants every axis marked ``AxisType.Auto`` so shard_map +
-    NamedSharding keep their classic semantics; old JAX has no axis types
-    (everything is implicitly auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names),
-                devices=devices,
-            )
-        except TypeError:  # make_mesh predates axis_types kwarg
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-    from jax.experimental import mesh_utils
-
-    devs = mesh_utils.create_device_mesh(axis_shapes, devices=devices)
-    return jax.sharding.Mesh(devs, axis_names)
+    """A Mesh with every axis marked ``AxisType.Auto`` so shard_map +
+    NamedSharding keep their classic semantics."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )
 
 
 # --------------------------------------------------------------------------- #
 # differentiable optimization barrier
 # --------------------------------------------------------------------------- #
-# ``lax.optimization_barrier`` exists on every supported JAX but only grew
-# autodiff rules after 0.4.37; this wrapper barriers the cotangents itself
-# so it differentiates everywhere.  The runtime uses it to force value
-# materialization at layer seams inside fused scan bodies (XLA's bf16 pass
-# may otherwise keep wider intermediates across the seam, changing bf16
-# roundings vs a per-layer scan-iteration boundary).
-_lax_barrier = jax.lax.optimization_barrier
-
-
+# The runtime uses it to force value materialization at layer seams inside
+# fused scan bodies (XLA's bf16 pass may otherwise keep wider intermediates
+# across the seam, changing bf16 roundings vs a per-layer scan-iteration
+# boundary).  The custom VJP barriers the cotangents as their own group, so
+# the backward seam is pinned the same way as the forward one.
 def _barrier_inexact(tree):
     """Barrier inexact leaves; pass ints/float0 cotangents through (XLA's
     optimization_barrier rejects float0, and integer leaves don't carry
@@ -103,16 +74,14 @@ def _barrier_inexact(tree):
     keep = [jnp_issubdtype_inexact(l) and getattr(l, "dtype", None) != f0
             for l in leaves]
     picked = [l for l, k in zip(leaves, keep) if k]
-    barriered = iter(_lax_barrier(picked) if picked else ())
+    barriered = iter(jax.lax.optimization_barrier(picked) if picked else ())
     out = [next(barriered) if k else l for l, k in zip(leaves, keep)]
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
 def jnp_issubdtype_inexact(x) -> bool:
-    import jax.numpy as _jnp
-
     dt = getattr(x, "dtype", None)
-    return dt is not None and _jnp.issubdtype(dt, _jnp.inexact)
+    return dt is not None and jnp.issubdtype(dt, jnp.inexact)
 
 
 @jax.custom_vjp
@@ -132,61 +101,35 @@ optimization_barrier.defvjp(_ob_fwd, _ob_bwd)
 
 
 # --------------------------------------------------------------------------- #
-# float8 dtypes (guarded)
+# float8 dtypes
 # --------------------------------------------------------------------------- #
 def float8_dtypes() -> dict:
-    """The float8 dtypes this JAX installation provides, as
-    ``{wire-format alias: dtype}`` (``fp8_e4m3`` -> float8_e4m3fn,
-    ``fp8_e5m2`` -> float8_e5m2).  Empty on installations without ml_dtypes
-    float8 support.  core.wire registers these as legal cast wire formats
-    (and, eventually, ParamStore formats) only when present, so call sites
-    never need a version check of their own."""
-    import jax.numpy as _jnp
-
-    out = {}
-    for alias, attr in (("fp8_e4m3", "float8_e4m3fn"),
-                        ("fp8_e5m2", "float8_e5m2")):
-        dt = getattr(_jnp, attr, None)
-        if dt is not None:
-            out[alias] = _jnp.dtype(dt)
-    return out
-
-
-HAS_FP8 = bool(float8_dtypes())
+    """The float8 dtypes as ``{format alias: dtype}``: ``fp8_e4m3`` ->
+    float8_e4m3fn, ``fp8_e5m2`` -> float8_e5m2.  core.wire registers these
+    as cast wire formats and core.store as ParamStore formats."""
+    return {"fp8_e4m3": jnp.dtype(jnp.float8_e4m3fn),
+            "fp8_e5m2": jnp.dtype(jnp.float8_e5m2)}
 
 
 # --------------------------------------------------------------------------- #
 # compiled-artifact introspection
 # --------------------------------------------------------------------------- #
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a one-element list of dicts on
-    JAX 0.4.x and a plain dict on newer releases; normalize to a dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``Compiled.cost_analysis()`` as a plain dict (empty when the backend
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 # --------------------------------------------------------------------------- #
 # tree utilities
 # --------------------------------------------------------------------------- #
 def tree_flatten_with_path(tree: Any):
-    t = getattr(jax, "tree", None)
-    if t is not None and hasattr(t, "flatten_with_path"):
-        return t.flatten_with_path(tree)
-    return jax.tree_util.tree_flatten_with_path(tree)
+    return jax.tree.flatten_with_path(tree)
 
 
 def tree_unflatten(treedef, leaves):
-    if hasattr(jax, "tree"):
-        return jax.tree.unflatten(treedef, leaves)
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+    return jax.tree.unflatten(treedef, leaves)
 
 
 def tree_map_with_path(f: Callable, tree: Any, *rest: Any):
-    """The ``*_with_path`` family migrated from ``jax.tree_util`` to
-    ``jax.tree`` across 0.4.x; prefer the new home."""
-    t = getattr(jax, "tree", None)
-    if t is not None and hasattr(t, "map_with_path"):
-        return t.map_with_path(f, tree, *rest)
-    return jax.tree_util.tree_map_with_path(f, tree, *rest)
+    return jax.tree.map_with_path(f, tree, *rest)

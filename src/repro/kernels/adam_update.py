@@ -26,7 +26,7 @@ def _adamw_kernel(s_ref, w_ref, g_ref, m_ref, v_ref, mask_ref,
     g = g_ref[...].astype(jnp.float32)
     m = b1 * m_ref[...] + (1.0 - b1) * g
     v = b2 * v_ref[...] + (1.0 - b2) * g * g
-    upd = (m / c1) / (jnp.sqrt(v / c2) + eps)
+    upd = m / (c1 * (jnp.sqrt(v / c2) + eps))  # = ref.adamw_update_ref
     w = w_ref[...]
     w_out[...] = w - lr * (upd + wd * mask_ref[...] * w)
     m_out[...] = m

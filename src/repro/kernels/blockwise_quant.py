@@ -6,8 +6,13 @@ never straddle tensors or device boundaries.  This is bandwidth-bound
 elementwise work -- exactly what wants a fused VMEM pass.
 
 Layout: x is viewed as (n_blocks, block); one grid row handles ``tile``
-quant blocks.  block is a multiple of 128 (lane width); TILE_BLOCKS x block
-tiles fit comfortably in VMEM (default 8 x 1024 x 4B = 32 KiB per ref).
+quant blocks.  block is a multiple of 128 (lane width).  The per-block
+scales ride as an (n_blocks, 1) column with (tile, 1) blocks: Mosaic
+admits a 1-D block only when it is the whole array or a multiple of 128,
+so a (TILE_BLOCKS,) vector of scales does not lower.  TILE_BLOCKS = 32 is
+int8's native sublane tiling on TPU (32 x 128 per packed tile), so the
+int8 codes tiles never split a packed tile; a 32 x 1024 x 4 B f32 tile is
+128 KiB per ref, far inside the scoped VMEM limit.
 
 Tiling rule (``_resolve_tile``): compiled (TPU) runs the TILE_BLOCKS grid;
 interpret mode (the CPU container, where the grid is unrolled by the
@@ -39,7 +44,7 @@ from jax.experimental import pallas as pl
 
 from ..quant.blockwise import _check_blocking, _check_scales
 
-TILE_BLOCKS = 8
+TILE_BLOCKS = 32
 
 
 def _resolve_tile(total: int, interpret: bool,
@@ -54,11 +59,11 @@ def _resolve_tile(total: int, interpret: bool,
 
 
 def _quant_kernel(x_ref, codes_ref, scales_ref):
-    x = x_ref[...].astype(jnp.float32)           # (TB, block)
-    absmax = jnp.max(jnp.abs(x), axis=1)         # (TB,)
+    x = x_ref[...].astype(jnp.float32)                     # (TB, block)
+    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)    # (TB, 1)
     scale = absmax / 127.0
     inv = jnp.where(scale > 0, 1.0 / jnp.maximum(scale, 1e-30), 0.0)
-    codes = jnp.clip(jnp.round(x * inv[:, None]), -127, 127)
+    codes = jnp.clip(jnp.round(x * inv), -127, 127)
     codes_ref[...] = codes.astype(jnp.int8)
     scales_ref[...] = scale
 
@@ -67,8 +72,18 @@ def _dequant_kernel(out_dtype, codes_ref, scales_ref, out_ref):
     # one fused pass: int8 -> f32 multiply -> target dtype, never writing
     # the f32 product to memory (out_ref IS the compute-dtype buffer)
     out_ref[...] = (
-        codes_ref[...].astype(jnp.float32) * scales_ref[...][:, None]
+        codes_ref[...].astype(jnp.float32) * scales_ref[...]
     ).astype(out_dtype)
+
+
+def blocks_spec(tb: int, block: int) -> pl.BlockSpec:
+    """(tb, block) tiles over an (n_blocks, block) view."""
+    return pl.BlockSpec((tb, block), lambda i: (i, 0))
+
+
+def scales_spec(tb: int) -> pl.BlockSpec:
+    """(tb, 1) tiles over the (n_blocks, 1) scales column."""
+    return pl.BlockSpec((tb, 1), lambda i: (i, 0))
 
 
 @functools.partial(jax.jit,
@@ -91,14 +106,11 @@ def quantize(x, *, block: int = 1024, interpret: bool = False,
     codes, scales = pl.pallas_call(
         _quant_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((tb, block), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((tb, block), lambda i: (i, 0)),
-            pl.BlockSpec((tb,), lambda i: (i,)),
-        ],
+        in_specs=[blocks_spec(tb, block)],
+        out_specs=[blocks_spec(tb, block), scales_spec(tb)],
         out_shape=[
             jax.ShapeDtypeStruct((total, block), jnp.int8),
-            jax.ShapeDtypeStruct((total,), jnp.float32),
+            jax.ShapeDtypeStruct((total, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xb)
@@ -123,18 +135,15 @@ def dequantize_into(codes, scales, *, block: int = 1024,
     for s in shape[:-1]:
         lead *= s
     cb = codes.reshape(lead * nb, block)
-    sb = scales.reshape(lead * nb)
+    sb = scales.reshape(lead * nb, 1)
     total = lead * nb
     tb = _resolve_tile(total, interpret, tile_blocks)
     out_dtype = jnp.dtype(out_dtype)
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, out_dtype),
         grid=(pl.cdiv(total, tb),),
-        in_specs=[
-            pl.BlockSpec((tb, block), lambda i: (i, 0)),
-            pl.BlockSpec((tb,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((tb, block), lambda i: (i, 0)),
+        in_specs=[blocks_spec(tb, block), scales_spec(tb)],
+        out_specs=blocks_spec(tb, block),
         out_shape=jax.ShapeDtypeStruct((total, block), out_dtype),
         interpret=interpret,
     )(cb, sb)

@@ -24,20 +24,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..quant.blockwise import _check_blocking
-from .blockwise_quant import _resolve_tile
+from .blockwise_quant import _resolve_tile, blocks_spec, scales_spec
 
 
 def _encode_ef_kernel(ct_ref, ef_ref, codes_ref, scales_ref, newef_ref):
     comp = ct_ref[...].astype(jnp.float32) + ef_ref[...]   # (TB, block)
-    absmax = jnp.max(jnp.abs(comp), axis=1)
+    absmax = jnp.max(jnp.abs(comp), axis=1, keepdims=True)  # (TB, 1)
     scale = absmax / 127.0
     inv = jnp.where(scale > 0, 1.0 / jnp.maximum(scale, 1e-30), 0.0)
-    codes = jnp.clip(jnp.round(comp * inv[:, None]), -127, 127)
+    codes = jnp.clip(jnp.round(comp * inv), -127, 127)
     codes_ref[...] = codes.astype(jnp.int8)
     scales_ref[...] = scale
     # codes holds integral f32 values in [-127, 127]: multiplying here is
     # bit-identical to dequantizing the int8 output
-    newef_ref[...] = comp - codes * scale[:, None]
+    newef_ref[...] = comp - codes * scale
 
 
 @functools.partial(jax.jit,
@@ -66,18 +66,12 @@ def encode_ef(ct, ef, *, block: int = 1024, interpret: bool = False,
     codes, scales, new_ef = pl.pallas_call(
         _encode_ef_kernel,
         grid=(pl.cdiv(total, tb),),
-        in_specs=[
-            pl.BlockSpec((tb, block), lambda i: (i, 0)),
-            pl.BlockSpec((tb, block), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, block), lambda i: (i, 0)),
-            pl.BlockSpec((tb,), lambda i: (i,)),
-            pl.BlockSpec((tb, block), lambda i: (i, 0)),
-        ],
+        in_specs=[blocks_spec(tb, block), blocks_spec(tb, block)],
+        out_specs=[blocks_spec(tb, block), scales_spec(tb),
+                   blocks_spec(tb, block)],
         out_shape=[
             jax.ShapeDtypeStruct((total, block), jnp.int8),
-            jax.ShapeDtypeStruct((total,), jnp.float32),
+            jax.ShapeDtypeStruct((total, 1), jnp.float32),
             jax.ShapeDtypeStruct((total, block), jnp.float32),
         ],
         interpret=interpret,

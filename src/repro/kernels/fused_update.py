@@ -19,11 +19,11 @@ Four store epilogues, one math core:
                  ``_requant`` the fused 8-bit Adam kernel uses, bitwise
                  identical to ``ops.quantize``).
 
-Tiling: flat epilogues run (rows, 128) lane tiles over the flat shard,
-zero-padding the tail lane (elementwise math on zero inputs stays zero,
-so the pad is inert and sliced back off); block epilogues run
-(TILE_BLOCKS, block) tiles and require the planner's align guarantee
-(shard last dim % block == 0).  Interpret mode (non-TPU) runs ONE
+Tiling: flat epilogues tile the buffer in place where its layout allows
+and otherwise as (rows, 128) lane tiles (``_flat_tiling``); block
+epilogues run (TILE_BLOCKS, block) tiles with (TILE_BLOCKS, 1) scale
+columns (blockwise_quant's layout rule) and require the planner's align
+guarantee (shard last dim % block == 0).  Interpret mode (non-TPU) runs ONE
 full-width tile per the kernels doctrine (blockwise_quant._resolve_tile).
 """
 from __future__ import annotations
@@ -36,7 +36,7 @@ from jax.experimental import pallas as pl
 
 from ..compat import float8_dtypes
 from .adam8bit_update import _dequant_log, _requant, _requant_log
-from .blockwise_quant import _resolve_tile
+from .blockwise_quant import _resolve_tile, blocks_spec, scales_spec
 
 LANES = 128
 TILE_ROWS = 64  # flat-epilogue grid rows (matches adam_update.py)
@@ -44,6 +44,50 @@ TILE_ROWS = 64  # flat-epilogue grid rows (matches adam_update.py)
 
 def _tile_rows(rows: int, interpret: bool) -> int:
     return max(1, rows) if interpret else max(1, min(TILE_ROWS, rows))
+
+
+def _flat_tiling(shape, interpret: bool):
+    """Tiling of a buffer for the elementwise (flat-epilogue) kernels:
+    ``(view, spec, grid, to_view, from_view)``.
+
+    A buffer of rank >= 2 whose last dim is lane-aligned (the layered
+    ``(n_layers, shard)`` groups) is tiled in place as ``(lead, last)``
+    with ``(lead, cols)`` blocks over its columns: reshaping it to
+    ``(rows, 128)`` would make XLA:TPU relayout every input and output (a
+    full HBM copy each, and a large temporary).  Anything else is viewed
+    as ``(rows, 128)`` lane tiles, zero-padding the tail lane (elementwise
+    math on zero inputs stays zero, so the pad is inert and sliced back
+    off).  Either way a compiled tile holds TILE_ROWS x 128 elements;
+    interpret mode runs one full-width tile."""
+    n = 1
+    for d in shape:
+        n *= d
+    if len(shape) >= 2 and shape[-1] % LANES == 0:
+        lead, last = n // shape[-1], shape[-1]
+        cols = last if interpret else min(
+            last, max(LANES, TILE_ROWS * LANES // lead // LANES * LANES))
+        return ((lead, last),
+                lambda: pl.BlockSpec((lead, cols), lambda i: (0, i)),
+                (pl.cdiv(last, cols),),
+                lambda x: x.reshape(lead, last),
+                lambda o: o.reshape(shape))
+    pn = -(-n // LANES) * LANES
+    rows = pn // LANES
+    tr = _tile_rows(rows, interpret)
+
+    def to_view(x):
+        flat = x.reshape(-1)
+        if pn != n:
+            flat = jnp.pad(flat, (0, pn - n))
+        return flat.reshape(rows, LANES)
+
+    def from_view(o):
+        return o.reshape(-1)[:n].reshape(shape) if pn != n \
+            else o.reshape(shape)
+
+    return ((rows, LANES),
+            lambda: pl.BlockSpec((tr, LANES), lambda i: (i, 0)),
+            (pl.cdiv(rows, tr),), to_view, from_view)
 
 
 def _scalar_stack(lr, b1, b2, eps, wd, c1, c2):
@@ -59,7 +103,7 @@ def _adam_math(s_ref, w_ref, g_ref, m_ref, v_ref, mask_ref):
     g = g_ref[...].astype(jnp.float32)
     m = b1 * m_ref[...] + (1.0 - b1) * g
     v = b2 * v_ref[...] + (1.0 - b2) * g * g
-    upd = (m / c1) / (jnp.sqrt(v / c2) + eps)
+    upd = m / (c1 * (jnp.sqrt(v / c2) + eps))
     w = w_ref[...].astype(jnp.float32)
     w2 = w - lr * (upd + wd * mask_ref[...] * w)
     return w2, m, v
@@ -69,11 +113,11 @@ def _adam8_math(s_ref, w_ref, g_ref, m8_ref, v8_ref, ms_ref, vs_ref,
                 mask_ref):
     lr, b1, b2, eps, wd, c1, c2, _ = [s_ref[i] for i in range(8)]
     g = g_ref[...].astype(jnp.float32)
-    m = m8_ref[...].astype(jnp.float32) * ms_ref[...][:, None]
+    m = m8_ref[...].astype(jnp.float32) * ms_ref[...]
     v = _dequant_log(v8_ref[...], vs_ref[...])
     m = b1 * m + (1.0 - b1) * g
     v = b2 * v + (1.0 - b2) * g * g
-    upd = (m / c1) / (jnp.sqrt(v / c2) + eps)
+    upd = m / (c1 * (jnp.sqrt(v / c2) + eps))
     w = w_ref[...].astype(jnp.float32)
     w2 = w - lr * (upd + wd * mask_ref[...] * w)
     return w2, m, v
@@ -192,8 +236,8 @@ def adamw_store_update(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2, *,
         _check_block(w.shape, block, "q8_block store update")
         nb = n // block
         tb = _resolve_tile(nb, interpret, None)
-        blk = lambda: pl.BlockSpec((tb, block), lambda i: (i, 0))
-        vec = lambda: pl.BlockSpec((tb,), lambda i: (i,))
+        blk = lambda: blocks_spec(tb, block)
+        vec = lambda: scales_spec(tb)
         r = lambda x: x.reshape(nb, block)
         codes, w2, scales, m2, v2 = pl.pallas_call(
             _adamw_q8_kernel,
@@ -204,7 +248,7 @@ def adamw_store_update(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2, *,
             out_shape=[
                 jax.ShapeDtypeStruct((nb, block), jnp.int8),
                 jax.ShapeDtypeStruct((nb, block), jnp.float32),
-                jax.ShapeDtypeStruct((nb,), jnp.float32),
+                jax.ShapeDtypeStruct((nb, 1), jnp.float32),
                 jax.ShapeDtypeStruct((nb, block), jnp.float32),
                 jax.ShapeDtypeStruct((nb, block), jnp.float32),
             ],
@@ -216,23 +260,9 @@ def adamw_store_update(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2, *,
                     w.shape[:-1] + (w.shape[-1] // block,))}
         return core, m2.reshape(w.shape), v2.reshape(w.shape)
 
-    # flat epilogues: lane tiles over the flat shard, inert zero pad
-    pn = -(-n // LANES) * LANES
-    rows = pn // LANES
-    tr = _tile_rows(rows, interpret)
-
-    def r(x):
-        flat = x.reshape(-1)
-        if pn != n:
-            flat = jnp.pad(flat, (0, pn - n))
-        return flat.reshape(rows, LANES)
-
-    def unpad(o):
-        return o.reshape(-1)[:n].reshape(w.shape) if pn != n \
-            else o.reshape(w.shape)
-
-    tile = lambda: pl.BlockSpec((tr, LANES), lambda i: (i, 0))
-    f32_out = jax.ShapeDtypeStruct((rows, LANES), jnp.float32)
+    # flat epilogues: elementwise, so any tiling of the buffer will do
+    view, tile, grid, r, unview = _flat_tiling(w.shape, interpret)
+    f32_out = jax.ShapeDtypeStruct(view, jnp.float32)
     args = (scalars, r(w), r(g), r(m), r(v), r(mask))
     in_specs = [pl.BlockSpec((8,), lambda i: (0,)),
                 tile(), tile(), tile(), tile(), tile()]
@@ -241,27 +271,26 @@ def adamw_store_update(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2, *,
         code_dt = jnp.dtype(float8_dtypes()[fmt])
         codes, w2, m2, v2 = pl.pallas_call(
             functools.partial(_adamw_fp8_kernel, code_dt),
-            grid=(pl.cdiv(rows, tr),),
+            grid=grid,
             in_specs=in_specs,
             out_specs=[tile(), tile(), tile(), tile()],
-            out_shape=[jax.ShapeDtypeStruct((rows, LANES), code_dt),
+            out_shape=[jax.ShapeDtypeStruct(view, code_dt),
                        f32_out, f32_out, f32_out],
             interpret=interpret,
         )(*args)
-        return ({"codes": unpad(codes), "master": unpad(w2)},
-                unpad(m2), unpad(v2))
+        return ({"codes": unview(codes), "master": unview(w2)},
+                unview(m2), unview(v2))
 
     out_dt = jnp.dtype(jnp.bfloat16 if fmt == "bf16" else jnp.float32)
     w2, m2, v2 = pl.pallas_call(
         functools.partial(_adamw_flat_kernel, out_dt),
-        grid=(pl.cdiv(rows, tr),),
+        grid=grid,
         in_specs=in_specs,
         out_specs=[tile(), tile(), tile()],
-        out_shape=[jax.ShapeDtypeStruct((rows, LANES), out_dt),
-                   f32_out, f32_out],
+        out_shape=[jax.ShapeDtypeStruct(view, out_dt), f32_out, f32_out],
         interpret=interpret,
     )(*args)
-    return unpad(w2), unpad(m2), unpad(v2)
+    return unview(w2), unview(m2), unview(v2)
 
 
 @functools.partial(jax.jit, static_argnames=("fmt", "block", "interpret"))
@@ -279,18 +308,18 @@ def adam8bit_store_update(w, g, m8, v8, ms, vs, mask, lr, b1, b2, eps, wd,
     n = w.size
     nb = n // block
     tb = _resolve_tile(nb, interpret, None)
-    blk = lambda: pl.BlockSpec((tb, block), lambda i: (i, 0))
-    vec = lambda: pl.BlockSpec((tb,), lambda i: (i,))
+    blk = lambda: blocks_spec(tb, block)
+    vec = lambda: scales_spec(tb)
     r = lambda x: x.reshape(nb, block)
     in_specs = [pl.BlockSpec((8,), lambda i: (0,)),
                 blk(), blk(), blk(), blk(), vec(), vec(), blk()]
-    args = (scalars, r(w), r(g), r(m8), r(v8), ms.reshape(nb),
-            vs.reshape(nb), r(mask))
+    args = (scalars, r(w), r(g), r(m8), r(v8), ms.reshape(nb, 1),
+            vs.reshape(nb, 1), r(mask))
     moment_outs = [
         jax.ShapeDtypeStruct((nb, block), jnp.int8),
         jax.ShapeDtypeStruct((nb, block), jnp.int8),
-        jax.ShapeDtypeStruct((nb,), jnp.float32),
-        jax.ShapeDtypeStruct((nb,), jnp.float32),
+        jax.ShapeDtypeStruct((nb, 1), jnp.float32),
+        jax.ShapeDtypeStruct((nb, 1), jnp.float32),
     ]
 
     def pack_moments(m8o, v8o, mso, vso):
@@ -306,7 +335,7 @@ def adam8bit_store_update(w, g, m8, v8, ms, vs, mask, lr, b1, b2, eps, wd,
             out_shape=[
                 jax.ShapeDtypeStruct((nb, block), jnp.int8),
                 jax.ShapeDtypeStruct((nb, block), jnp.float32),
-                jax.ShapeDtypeStruct((nb,), jnp.float32),
+                jax.ShapeDtypeStruct((nb, 1), jnp.float32),
             ] + moment_outs,
             interpret=interpret,
         )(*args)

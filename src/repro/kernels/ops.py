@@ -143,7 +143,10 @@ def adamw_store_update(w, g, m, v, mask, *, lr, b1, b2, eps, wd, c1, c2,
     store format.  Returns ``(core, m2, v2)``; ``core`` mirrors
     ``ParamStore.rebuild``.
 
-    PARITY: BITWISE -- vs the jitted kernels/ref.py composition.
+    PARITY: BITWISE -- vs the jitted kernels/ref.py composition on a
+    compiler that contracts no multiply-add (XLA:CPU pinned FMA-free, as
+    the tests run it), and on TPU v5e, Mosaic kernel vs XLA:TPU reference
+    (chip_smoke.py checks it on a real group shard; DESIGN.md §Kernels).
     """
     return _adamw_store(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2,
                         fmt=fmt, block=block, interpret=_interpret())

@@ -34,6 +34,12 @@ layout permits (case B -> per-row scales, any slice; case A -> block-
 aligned slices), so the serve path's KV head slicing stays on the int8
 GEMM instead of densifying the whole projection.
 
+Tiling: the grid runs (row tiles of TILE_M activations) x (nj column
+groups).  Each step holds a (TILE_M, K) activation tile, its group's (1, K)
+scale row and the (K, N/nj) codes -- row quantization is per activation
+row, so tiling M changes no value.  Interpret mode (non-TPU) runs all rows
+as one tile (blockwise_quant._resolve_tile's doctrine).
+
 Parity class: ALLCLOSE vs the dense reference (x @ dequantize(w)) -- the
 activation row-quantization is new error by design, bounded by ~1/254
 relative per element.  The kernel-vs-jnp-equivalent comparison is bitwise
@@ -48,7 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..quant.blockwise import _check_blocking, _check_scales
-from .blockwise_quant import _resolve_tile  # noqa: F401  (shared tiling doc)
+
+TILE_M = 256  # activation rows per grid step on TPU (a multiple of 8)
 
 
 def quant_eligible(shape: tuple[int, ...], block: int) -> bool:
@@ -79,16 +86,16 @@ def fold_scales(scales_flat, k: int, n: int, block: int) -> jax.Array:
 
 
 def _q8mm_kernel(out_dtype, x_ref, s_ref, w_ref, o_ref):
-    x = x_ref[...].astype(jnp.float32)                # (M, K)
-    a = x * s_ref[...]                                # fold w-scales, (M, K)
-    rmax = jnp.max(jnp.abs(a), axis=1)                # per-row absmax
+    x = x_ref[...].astype(jnp.float32)                # (TM, K)
+    a = x * s_ref[...]                                # fold w-scales, (TM, K)
+    rmax = jnp.max(jnp.abs(a), axis=1, keepdims=True)  # per-row absmax
     rs = rmax / 127.0
     inv = jnp.where(rs > 0, 1.0 / jnp.maximum(rs, 1e-30), 0.0)
-    a8 = jnp.clip(jnp.round(a * inv[:, None]), -127, 127).astype(jnp.int8)
+    a8 = jnp.clip(jnp.round(a * inv), -127, 127).astype(jnp.int8)
     acc = jax.lax.dot_general(
         a8, w_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)             # int8 x int8 -> int32
-    o_ref[...] = (acc.astype(jnp.float32) * rs[:, None]).astype(out_dtype)
+    o_ref[...] = (acc.astype(jnp.float32) * rs).astype(out_dtype)
 
 
 @functools.partial(jax.jit,
@@ -123,18 +130,20 @@ def q8_matmul(x, codes, scales, *, block: int = 1024, out_dtype=None,
     s2 = fold_scales(scales, k, n, block)             # (nj, K)
     nj = s2.shape[0]
     ncols = n // nj
+    tm = max(1, m) if interpret else min(TILE_M, m)
     out = pl.pallas_call(
         functools.partial(_q8mm_kernel, out_dtype),
-        grid=(nj,),
+        grid=(pl.cdiv(m, tm), nj),
         in_specs=[
-            pl.BlockSpec((m, k), lambda j: (0, 0)),
-            pl.BlockSpec((1, k), lambda j: (j, 0)),
-            pl.BlockSpec((k, ncols), lambda j: (0, j)),
+            pl.BlockSpec((tm, k), lambda i, j: (i, 0)),
+            # one (1, K) scale row per column group, leading dim squeezed
+            pl.BlockSpec((pl.squeezed, 1, k), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((k, ncols), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((m, ncols), lambda j: (0, j)),
+        out_specs=pl.BlockSpec((tm, ncols), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
-    )(xm, s2, codes)
+    )(xm, s2.reshape(nj, 1, k), codes)
     return out.reshape(lead + (n,))
 
 

@@ -47,7 +47,10 @@ def adamw_update_ref(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2):
     g = g.astype(jnp.float32)
     m2 = b1 * m + (1 - b1) * g
     v2 = b2 * v + (1 - b2) * g * g
-    upd = (m2 / c1) / (jnp.sqrt(v2 / c2) + eps)
+    # one divide by the whole denominator: XLA's simplifier rewrites
+    # (m / c1) / d into m / (c1 * d) anyway, and the Pallas kernels spell
+    # it this way so Mosaic runs the op sequence XLA:TPU runs
+    upd = m2 / (c1 * (jnp.sqrt(v2 / c2) + eps))
     w2 = w - lr * (upd + wd * mask * w)
     return w2, m2, v2
 

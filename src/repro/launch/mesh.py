@@ -22,11 +22,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(tuple(sizes.values()), tuple(sizes))
 
 
-def make_local_mesh(data: int = 1, model: int = 1, pod: int | None = None):
-    """Mesh over however many (possibly host-platform) devices exist."""
+def make_local_mesh(data: int = 1, model: int = 1, pod: int | None = None,
+                    *, devices=None):
+    """Mesh over however many (possibly host-platform) devices exist, or
+    over ``devices`` (e.g. ``jax.devices()[:1]`` for a one-chip mesh on a
+    four-chip host)."""
     if pod is not None:
-        return make_mesh((pod, data, model), ("pod", "data", "model"))
-    return make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"),
+                         devices=devices)
+    return make_mesh((data, model), ("data", "model"), devices=devices)
 
 
 # TPU v5e hardware constants (per chip) for the roofline model

@@ -3,15 +3,82 @@
     PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --reduced \
         --steps 50 --batch 8 --seq 128
 
-On this CPU container, --reduced (smoke-scale) is the realistic mode; the
-full configs are exercised by the dry run.  The driver wires data pipeline,
-FSDP runtime, optimizer, metrics, and periodic checkpointing.
+On a CPU host, --reduced (smoke-scale) is the realistic mode; the full
+configs are exercised by the dry run and, cut to one chip's share, by
+``chip_smoke.py`` on a TPU.  ``main`` wires data pipeline, FSDP runtime,
+optimizer, metrics, and periodic checkpointing.
+
+``build`` and ``train_loop`` are the training main path: ``main`` and
+``chip_smoke.py`` both run through them.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import pathlib
 import time
+from typing import Callable, Optional
+
+#: the checkout this module runs from (src/repro/launch/train.py -> root)
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, places the cache from outside
+    and JAX reads it itself, so nothing is set here.  Otherwise the cache
+    lives at the fixed path ``<checkout>/.jax_cache``, never one built from
+    a temp name, a pid or the time, so the next run in the same checkout
+    finds what this one compiled."""
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build(cfg, mesh, *, planner: str = "ragged", policies=None,
+          cost_model=None):
+    """(runtime, optimizer) for ``cfg`` on ``mesh``: the model, its
+    ShardingPlan resolved by ``FSDPRuntime``, and the config's optimizer."""
+    from ..configs import build_model
+    from ..core.fsdp import FSDPRuntime
+    from ..optim import make_optimizer
+
+    runtime = FSDPRuntime(build_model(cfg), mesh, planner=planner,
+                          policies=policies, cost_model=cost_model)
+    return runtime, make_optimizer(cfg)
+
+
+def n_params(runtime) -> int:
+    """Parameters the plan holds, over every group and layer."""
+    return sum(int(lo.plan.payload) * (lo.n_layers or 1) * lo.outer_size
+               for lo in runtime.layouts.values())
+
+
+def train_loop(runtime, step_fn: Callable, params, opt_state,
+               batch_for: Callable, steps: int, *, start: int = 0,
+               on_step: Optional[Callable] = None):
+    """Run ``step_fn`` (``runtime.make_train_step(optimizer)`` or its
+    compiled executable) for steps ``start .. steps-1`` on ``batch_for(i)``.
+    ``on_step(i, params, opt_state, metrics)`` sees every step's result.
+    Returns the final ``(params, opt_state)``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    step = jax.device_put(jnp.int32(start), NamedSharding(runtime.mesh, P()))
+    for i in range(start, steps):
+        params, opt_state, step, metrics = step_fn(
+            params, opt_state, step, batch_for(i))
+        if on_step is not None:
+            on_step(i, params, opt_state, metrics)
+    return params, opt_state
 
 
 def main():
@@ -54,15 +121,12 @@ def main():
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
 
-    import jax
-    import jax.numpy as jnp
-
     from ..checkpoint import ckpt
-    from ..configs import build_model, get_config
-    from ..core.fsdp import FSDPRuntime
+    from ..configs import get_config
     from ..data.pipeline import DataConfig, SyntheticStream
-    from ..optim import make_optimizer
     from .mesh import make_local_mesh
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -80,16 +144,14 @@ def main():
             par = dataclasses.replace(par, tp=1)
         cfg = dataclasses.replace(cfg, parallel=par)
     mesh = make_local_mesh(args.data, args.model)
-    model = build_model(cfg)
     cost_model = None
     if args.profile:
         from ..core.policy import CostModel
 
         cost_model = CostModel.from_profile(args.profile)
-    runtime = FSDPRuntime(model, mesh, planner=args.planner,
-                          policies=args.policies, cost_model=cost_model)
+    runtime, optimizer = build(cfg, mesh, planner=args.planner,
+                               policies=args.policies, cost_model=cost_model)
     print(runtime.plan.describe())
-    optimizer = make_optimizer(cfg)
     if args.verify:
         from ..analysis import verify_runtime
 
@@ -102,8 +164,6 @@ def main():
     opt_state = optimizer.init(runtime)
     start = 0
     if args.resume and args.ckpt:
-        import pathlib
-
         if (pathlib.Path(args.ckpt) / "meta.json").exists():
             params, start, opt_state = ckpt.load(args.ckpt, runtime,
                                                  opt_state)
@@ -112,20 +172,13 @@ def main():
     stream = SyntheticStream(
         DataConfig(cfg.vocab, args.seq, args.batch, seed=args.seed), cfg)
 
-    n_params = sum(
-        int(lo.plan.payload) * (lo.n_layers or 1) * lo.outer_size
-        for lo in runtime.layouts.values()
-    )
-    print(f"arch={cfg.name} params={n_params/1e6:.1f}M "
+    print(f"arch={cfg.name} params={n_params(runtime)/1e6:.1f}M "
           f"planner={args.planner} optimizer={cfg.optimizer} "
           f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
-    step = jnp.int32(start)
     t0 = time.time()
-    for i in range(start, args.steps):
-        batch = stream.shard(stream.batch(i), runtime)
-        params, opt_state, step, metrics = step_fn(
-            params, opt_state, step, batch)
+
+    def on_step(i, params, opt_state, metrics):
         if i % args.log_every == 0 or i == args.steps - 1:
             dt = time.time() - t0
             tok_s = (i + 1) * args.batch * args.seq / max(dt, 1e-9)
@@ -135,6 +188,11 @@ def main():
         if args.ckpt and args.ckpt_every and (i + 1) % args.ckpt_every == 0:
             ckpt.save(args.ckpt, runtime, params, opt_state, step=i + 1)
             print(f"checkpoint @ step {i+1} -> {args.ckpt}")
+
+    params, opt_state = train_loop(
+        runtime, step_fn, params, opt_state,
+        lambda i: stream.shard(stream.batch(i), runtime), args.steps,
+        start=start, on_step=on_step)
     if args.ckpt:
         ckpt.save(args.ckpt, runtime, params, opt_state, step=args.steps)
         print(f"final checkpoint -> {args.ckpt}")

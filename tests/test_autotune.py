@@ -332,7 +332,9 @@ def test_snap_chunk_divisor_rule():
 
 _DRIVER_CHUNK_8DEV = textwrap.dedent("""
     import os, sys, json, dataclasses
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # FMA-free XLA:CPU, as in conftest.py: the comparison is bitwise
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                               "--xla_cpu_max_isa=AVX")
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from jax.experimental.shard_map import shard_map

@@ -1,8 +1,7 @@
 """fp8 ParamStore formats (fp8_e4m3 / fp8_e5m2): float8 codes + fp32
 master shard.
 
-Guarantees under test (all guarded on ``compat.float8_dtypes()`` being
-non-empty -- the whole module skips on a JAX without float8):
+Guarantees under test:
 
   * state structure: ``{"codes", "master"}``, codes always the exact fp8
     cast of the master (create, rebuild, and through real training);
@@ -50,9 +49,6 @@ from repro.core.schedule import APPROX_VARIANTS, CommSchedule
 from repro.core.store import ParamStore
 from repro.launch.mesh import make_local_mesh
 from repro.optim import make_optimizer
-
-pytestmark = pytest.mark.skipif(
-    not compat.HAS_FP8, reason="installed JAX has no float8 dtypes")
 
 MESH = make_local_mesh(1, 1)
 

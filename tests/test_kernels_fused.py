@@ -311,7 +311,9 @@ def test_dequantize_into_property(out_dtype, block, nblocks, seed):
 
 _DRIVER_8DEV = textwrap.dedent("""
     import os, json, dataclasses
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # FMA-free XLA:CPU, as in conftest.py: the comparison is bitwise
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                               "--xla_cpu_max_isa=AVX")
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config, build_model
     from repro.configs.base import ParallelConfig

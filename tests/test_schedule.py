@@ -307,7 +307,9 @@ def test_pair_prefetch_issue_order_is_explicit_in_backward():
 
 _RING_DRIVER = textwrap.dedent("""
     import os, sys, json, dataclasses
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # FMA-free XLA:CPU, as in conftest.py: the comparison is bitwise
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                               "--xla_cpu_max_isa=AVX")
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config, build_model
     from repro.configs.base import ParallelConfig

@@ -259,7 +259,9 @@ def test_q8_rejects_unaligned_baseline_planner():
 
 _DRIVER_8DEV = textwrap.dedent("""
     import os, sys, json, dataclasses, tempfile
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # FMA-free XLA:CPU, as in conftest.py: the comparison is bitwise
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                               "--xla_cpu_max_isa=AVX")
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config, build_model
     from repro.configs.base import ParallelConfig

@@ -1,0 +1,157 @@
+"""Compile for a TPU v5e that is described, not attached.
+
+Every Pallas kernel in ``repro.kernels`` and one whole train step of the
+chip smoke configuration (Granite-3.0-1B-A400M at published widths, 8
+layers, batch 1 x 4096) are compiled by the TPU compiler installed with
+libtpu, with ``interpret=False``: Mosaic refuses what interpret mode runs
+happily (a 1-D block that is neither the whole array nor a multiple of
+128, a tile past the scoped VMEM limit), and XLA refuses a step that does
+not fit the chip's memory.  Nothing runs, so these tests say nothing about
+values or times -- the interpret-mode parity suites own values.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file.  Kernel shapes are Granite's shard widths: one layer of
+the ``layers`` group (3,180,544 elements on one chip) and one sequence of
+4096 tokens; the whole-step test runs the update at all 8 layers.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.compat import float8_dtypes
+
+GIB = 1 << 30
+BLOCK = 1024
+LAYERS = (1, 3_180_544)              # one layer of Granite's `layers` shard
+LAYER_BLOCKS = (1, 3_180_544 // BLOCK)
+TOKENS = 4096
+STORE_FMTS = ["fp32", "bf16", "q8_block"] + sorted(float8_dtypes())
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these tests
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topology = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler to describe the chip with
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield topology
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_cases():
+    """name -> ``lower(sds)``, where ``sds(shape, dtype)`` makes an argument
+    and ``lower`` returns the kernel's Lowered."""
+    from repro.kernels import (adam8bit_update as a8, adam_update as aw,
+                               blockwise_quant as bq, encode_ef as ee,
+                               fused_update as fu, q8_matmul as qm)
+
+    f32, bf16, i8 = jnp.float32, jnp.bfloat16, jnp.int8
+    L, NB = LAYERS, LAYER_BLOCKS
+    flat = (L[0] * L[1],)
+
+    def sc(s):
+        return [s((), f32)] * 7
+
+    cases = {
+        "quantize": lambda s: bq.quantize.lower(
+            s(L, f32), block=BLOCK, interpret=False),
+        "dequantize_into": lambda s: bq.dequantize_into.lower(
+            s(L, i8), s(NB, f32), block=BLOCK, out_dtype=bf16,
+            interpret=False),
+        "encode_ef": lambda s: ee.encode_ef.lower(
+            s(L, bf16), s(L, f32), block=BLOCK, interpret=False),
+        # attention projection (N % block == 0) and expert up-projection
+        # (block % N == 0) of Granite, one sequence of activations
+        "q8_matmul_d1024": lambda s: qm.q8_matmul.lower(
+            s((TOKENS, 1024), bf16), s((1024, 1024), i8),
+            s((1024,), f32), block=BLOCK, interpret=False),
+        "q8_matmul_ff512": lambda s: qm.q8_matmul.lower(
+            s((TOKENS, 1024), bf16), s((1024, 512), i8), s((512,), f32),
+            block=BLOCK, interpret=False),
+        "adamw_update": lambda s: aw.adamw_update.lower(
+            *[s(flat, f32)] * 5, *sc(s), interpret=False),
+        "adam8bit_update": lambda s: a8.adam8bit_update.lower(
+            s(flat, f32), s(flat, f32), s(flat, i8), s(flat, i8),
+            s((flat[0] // BLOCK,), f32), s((flat[0] // BLOCK,), f32),
+            s(flat, f32), *sc(s), block=BLOCK, interpret=False),
+    }
+    for fmt in STORE_FMTS:
+        wdt = bf16 if fmt == "bf16" else f32
+        cases[f"adamw_store_update_{fmt}"] = (
+            lambda s, fmt=fmt, wdt=wdt: fu.adamw_store_update.lower(
+                s(L, wdt), s(L, f32), s(L, f32), s(L, f32), s(L, f32),
+                *sc(s), fmt=fmt, block=BLOCK, interpret=False))
+        cases[f"adam8bit_store_update_{fmt}"] = (
+            lambda s, fmt=fmt, wdt=wdt: fu.adam8bit_store_update.lower(
+                s(L, wdt), s(L, f32), s(L, i8), s(L, i8), s(NB, f32),
+                s(NB, f32), s(L, f32), *sc(s), fmt=fmt, block=BLOCK,
+                interpret=False))
+    return cases
+
+
+KERNELS = ["quantize", "dequantize_into", "encode_ef", "q8_matmul_d1024",
+           "q8_matmul_ff512", "adamw_update", "adam8bit_update"] + [
+    f"{k}_{fmt}" for k in ("adamw_store_update", "adam8bit_store_update")
+    for fmt in STORE_FMTS]
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e(name, one_chip):
+    lowered = _kernel_cases()[name](
+        lambda shape, dt: _sds(one_chip, shape, dt))
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_smoke_train_step_compiles_for_v5e(topo, monkeypatch):
+    """The whole chip-smoke step at full width: Mosaic kernels inside, and
+    arguments plus temporaries within one chip's 16 GiB."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch.mesh import make_local_mesh
+    from repro.launch.train import build
+
+    # ops dispatches on jax.default_backend(), which is the CPU here
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("granite-moe-1b-a400m"),
+                              n_layers=8)
+    mesh = make_local_mesh(1, 1, devices=topo.devices[:1])
+    runtime, optimizer = build(cfg, mesh)
+    tokens = jax.ShapeDtypeStruct((1, TOKENS), jnp.int32)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        tokens.shape, tokens.dtype,
+        sharding=NamedSharding(mesh, runtime.batch_pspec(
+            {"tokens": tokens})["tokens"]))}
+    step = jax.ShapeDtypeStruct((), jnp.int32,
+                                sharding=NamedSharding(mesh, P()))
+    compiled = runtime.make_train_step(optimizer).lower(
+        runtime.param_shapes(), optimizer.state_shapes(runtime), step,
+        batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used <= 16 * GIB, (mem.argument_size_in_bytes,
+                              mem.temp_size_in_bytes)

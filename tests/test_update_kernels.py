@@ -254,8 +254,6 @@ def test_fused_q8_update_fewer_f32_streams():
 
 
 def test_fused_fp8_update_fewer_f32_streams():
-    if not float8_dtypes():
-        pytest.skip("installed JAX has no float8 dtypes")
     n = 8 * 1024
     w, g, m, v, mask = _adamw_inputs(n)
 
@@ -279,7 +277,9 @@ def test_fused_fp8_update_fewer_f32_streams():
 
 _DRIVER_8DEV = textwrap.dedent("""
     import os, json, functools
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # FMA-free XLA:CPU, as in conftest.py: the comparison is bitwise
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                               "--xla_cpu_max_isa=AVX")
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.compat import shard_map
