@@ -42,6 +42,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import spans
 from ..compat import optimization_barrier, shard_map
 from ..models.transformer import GroupDef
 from .dbuffer import DBuffer
@@ -590,6 +591,7 @@ class FSDPRuntime:
                                    if lo.store.quantized
                                    else lo.store.storage_dtype)
 
+                            @jax.named_scope(spans.FSDP_GRAD_SYNC)
                             def rs(ct1, ef1, lo=lo, sched=sched,
                                    rcodec=rcodec, pdt=pdt):
                                 return codec_reduce_scatter(
@@ -620,23 +622,27 @@ class FSDPRuntime:
                 grads, new_efs = split_ef(grads)
 
                 # cross-device normalization
-                nll_g = lax.psum(nll, self.batch_axes) if self.batch_axes else nll
-                w_g = lax.psum(w, self.batch_axes) if self.batch_axes else w
-                grads = self._reduce_grads(grads)
-                scale = 1.0 / jnp.maximum(w_g, 1.0)
-                grads = jax.tree.map(lambda g: g * scale, grads)
-                new_params, new_opt = optimizer.update(
-                    self, params, grads, opt_state, step)
+                with jax.named_scope(spans.FSDP_GRAD_SYNC):
+                    nll_g = (lax.psum(nll, self.batch_axes)
+                             if self.batch_axes else nll)
+                    w_g = lax.psum(w, self.batch_axes) if self.batch_axes else w
+                    grads = self._reduce_grads(grads)
+                    scale = 1.0 / jnp.maximum(w_g, 1.0)
+                    grads = jax.tree.map(lambda g: g * scale, grads)
+                with jax.named_scope(spans.OPTIM_UPDATE):
+                    new_params, new_opt = optimizer.update(
+                        self, params, grads, opt_state, step)
                 for n in ef_groups:
                     # optimizers are EF-oblivious (rebuild returns the core
                     # state); re-attach the updated residual here
                     new_params[n] = self.layouts[n].store.attach_ef(
                         new_params[n], new_efs[n])
-                metrics = {
-                    "loss": nll_g / jnp.maximum(w_g, 1.0),
-                    "tokens": w_g,
-                    "grad_norm": _global_norm(self, grads),
-                }
+                with jax.named_scope(spans.FSDP_GRAD_SYNC):
+                    metrics = {
+                        "loss": nll_g / jnp.maximum(w_g, 1.0),
+                        "tokens": w_g,
+                        "grad_norm": _global_norm(self, grads),
+                    }
                 return new_params, new_opt, metrics
 
             opt_specs = optimizer.pspecs(self)
@@ -858,17 +864,19 @@ class _ParamGetter:
         a flat slice for fp32/bf16 stores, a codes/master/scales dict for
         q8_block (the quantized wire)."""
         lo = self.rt.layouts[name]
-        return lo.store.gather(
-            local, lo.fsdp_axes, lo.fsdp_axis_sizes, self.rt.sched_for(name),
-            self.rt.compute_dtype,
-            defer_ef=self.defer_ef and lo.store.has_ef)
+        with jax.named_scope(spans.FSDP_GATHER):
+            return lo.store.gather(
+                local, lo.fsdp_axes, lo.fsdp_axis_sizes,
+                self.rt.sched_for(name), self.rt.compute_dtype,
+                defer_ef=self.defer_ef and lo.store.has_ef)
 
     def _quant_group(self, name: str) -> bool:
         return self.quant_matmul and self.rt.layouts[name].store.quantized
 
     def _gather_unpack(self, name: str, local: jax.Array):
-        return self.rt.layouts[name].buffer.unpack(
-            self._gather_flat(name, local))
+        flat = self._gather_flat(name, local)
+        with jax.named_scope(spans.FSDP_UNPACK):
+            return self.rt.layouts[name].buffer.unpack(flat)
 
     def globals(self, group: str) -> dict[str, jax.Array]:
         return self._gather_unpack(group, self.bufs[group])
@@ -912,13 +920,15 @@ class _ParamGetter:
                     # dequantize decision to unpack_quant (eligible 2-D
                     # weights never dequantize -- ops.q8_matmul)
                     lo = self.rt.layouts[g]
-                    out.append(lo.store.gather_payload(
-                        lb, lo.fsdp_axes, lo.fsdp_axis_sizes,
-                        self.rt.sched_for(g)))
+                    with jax.named_scope(spans.FSDP_GATHER):
+                        out.append(lo.store.gather_payload(
+                            lb, lo.fsdp_axes, lo.fsdp_axis_sizes,
+                            self.rt.sched_for(g)))
                 else:
                     out.append(self._gather_flat(g, lb))
             return tuple(out)
 
+        @jax.named_scope(spans.FSDP_UNPACK)
         def unpack_all(gathered):
             p = {}
             for g, gb in zip(groups, gathered):

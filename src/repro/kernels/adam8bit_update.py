@@ -97,6 +97,7 @@ def adam8bit_update(w, g, m8, v8, ms, vs, mask, lr, b1, b2, eps, wd, c1, c2,
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
+        name="adam8bit_update",
         interpret=interpret,
     )(scalars, r(w, jnp.float32), r(g, jnp.float32), r(m8, jnp.int8),
       r(v8, jnp.int8), ms.reshape(nb, 1), vs.reshape(nb, 1),

@@ -65,6 +65,7 @@ def adamw_update(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2,
             pl.BlockSpec((tr, LANES), lambda i: (i, 0)),
         ],
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.float32)] * 3,
+        name="adamw_update",
         interpret=interpret,
     )(scalars, r(w), r(g), r(m), r(v), r(mask))
     return tuple(o.reshape(w.shape) for o in outs)

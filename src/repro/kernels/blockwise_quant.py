@@ -112,6 +112,7 @@ def quantize(x, *, block: int = 1024, interpret: bool = False,
             jax.ShapeDtypeStruct((total, block), jnp.int8),
             jax.ShapeDtypeStruct((total, 1), jnp.float32),
         ],
+        name="quantize",
         interpret=interpret,
     )(xb)
     return codes.reshape(shape), scales.reshape(shape[:-1] + (nb,))
@@ -145,6 +146,7 @@ def dequantize_into(codes, scales, *, block: int = 1024,
         in_specs=[blocks_spec(tb, block), scales_spec(tb)],
         out_specs=blocks_spec(tb, block),
         out_shape=jax.ShapeDtypeStruct((total, block), out_dtype),
+        name="dequantize_into",
         interpret=interpret,
     )(cb, sb)
     return out.reshape(shape)

@@ -74,6 +74,7 @@ def encode_ef(ct, ef, *, block: int = 1024, interpret: bool = False,
             jax.ShapeDtypeStruct((total, 1), jnp.float32),
             jax.ShapeDtypeStruct((total, block), jnp.float32),
         ],
+        name="encode_ef",
         interpret=interpret,
     )(ctb, efb)
     return (codes.reshape(shape), scales.reshape(shape[:-1] + (nb,)),

@@ -252,6 +252,7 @@ def adamw_store_update(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2, *,
                 jax.ShapeDtypeStruct((nb, block), jnp.float32),
                 jax.ShapeDtypeStruct((nb, block), jnp.float32),
             ],
+            name="adamw_store_update",
             interpret=interpret,
         )(scalars, r(w), r(g), r(m), r(v), r(mask))
         core = {"codes": codes.reshape(w.shape),
@@ -276,6 +277,7 @@ def adamw_store_update(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2, *,
             out_specs=[tile(), tile(), tile(), tile()],
             out_shape=[jax.ShapeDtypeStruct(view, code_dt),
                        f32_out, f32_out, f32_out],
+            name="adamw_store_update",
             interpret=interpret,
         )(*args)
         return ({"codes": unview(codes), "master": unview(w2)},
@@ -288,6 +290,7 @@ def adamw_store_update(w, g, m, v, mask, lr, b1, b2, eps, wd, c1, c2, *,
         in_specs=in_specs,
         out_specs=[tile(), tile(), tile()],
         out_shape=[jax.ShapeDtypeStruct(view, out_dt), f32_out, f32_out],
+        name="adamw_store_update",
         interpret=interpret,
     )(*args)
     return unview(w2), unview(m2), unview(v2)
@@ -337,6 +340,7 @@ def adam8bit_store_update(w, g, m8, v8, ms, vs, mask, lr, b1, b2, eps, wd,
                 jax.ShapeDtypeStruct((nb, block), jnp.float32),
                 jax.ShapeDtypeStruct((nb, 1), jnp.float32),
             ] + moment_outs,
+            name="adam8bit_store_update",
             interpret=interpret,
         )(*args)
         core = {"codes": codes.reshape(w.shape),
@@ -356,6 +360,7 @@ def adam8bit_store_update(w, g, m8, v8, ms, vs, mask, lr, b1, b2, eps, wd,
                 jax.ShapeDtypeStruct((nb, block), code_dt),
                 jax.ShapeDtypeStruct((nb, block), jnp.float32),
             ] + moment_outs,
+            name="adam8bit_store_update",
             interpret=interpret,
         )(*args)
         core = {"codes": codes.reshape(w.shape),
@@ -370,6 +375,7 @@ def adam8bit_store_update(w, g, m8, v8, ms, vs, mask, lr, b1, b2, eps, wd,
         out_specs=[blk(), blk(), blk(), vec(), vec()],
         out_shape=[jax.ShapeDtypeStruct((nb, block), out_dt)]
         + moment_outs,
+        name="adam8bit_store_update",
         interpret=interpret,
     )(*args)
     return (w2.reshape(w.shape),) + pack_moments(m8o, v8o, mso, vso)

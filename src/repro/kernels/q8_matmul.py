@@ -142,6 +142,7 @@ def q8_matmul(x, codes, scales, *, block: int = 1024, out_dtype=None,
         ],
         out_specs=pl.BlockSpec((tm, ncols), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        name="q8_matmul",
         interpret=interpret,
     )(xm, s2.reshape(nj, 1, k), codes)
     return out.reshape(lead + (n,))

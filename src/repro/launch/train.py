@@ -67,17 +67,28 @@ def train_loop(runtime, step_fn: Callable, params, opt_state,
     """Run ``step_fn`` (``runtime.make_train_step(optimizer)`` or its
     compiled executable) for steps ``start .. steps-1`` on ``batch_for(i)``.
     ``on_step(i, params, opt_state, metrics)`` sees every step's result.
-    Returns the final ``(params, opt_state)``."""
+    Returns the final ``(params, opt_state)``.
+
+    Each iteration is a ``train.step`` span holding ``train.batch`` and
+    ``train.dispatch`` (``repro.spans``), seen by any ``jax.profiler``
+    trace taken around the loop."""
     import jax
     import jax.numpy as jnp
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
     from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from .. import spans
 
     step = jax.device_put(jnp.int32(start), NamedSharding(runtime.mesh, P()))
     for i in range(start, steps):
-        params, opt_state, step, metrics = step_fn(
-            params, opt_state, step, batch_for(i))
-        if on_step is not None:
-            on_step(i, params, opt_state, metrics)
+        with StepTraceAnnotation(spans.TRAIN_STEP, step_num=i):
+            with TraceAnnotation(spans.TRAIN_BATCH):
+                batch = batch_for(i)
+            with TraceAnnotation(spans.TRAIN_DISPATCH):
+                params, opt_state, step, metrics = step_fn(
+                    params, opt_state, step, batch)
+            if on_step is not None:
+                on_step(i, params, opt_state, metrics)
     return params, opt_state
 
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import spans
 from ..core.ragged import ShardDim, TensorSpec
 from . import layers as L
 from .moe import moe_ffn
@@ -230,39 +231,46 @@ class DecoderLM:
                     pg=None, prefix="", sp=False):
         cfg = self.cfg
         tp_axis = pg.tp_axis if self.tp > 1 else None
-        h = L.rms_norm(x, p[prefix + "ln1"], cfg.norm_eps)
-        h = L.gather_seq(h, tp_axis, sp)  # SP: gather seq for attention
-        attn_cfg = _AttnView(cfg, prefix)
-        out, new_cache = L.attention(
-            attn_cfg, p, h, q_pos=q_pos, cache=cache, cache_index=cache_index,
-            window=window, tp_axis=tp_axis, tp=self.tp, prefix=prefix, sp=sp,
-        )
-        if cfg.post_norms:
-            out = L.rms_norm(out, p[prefix + "post_ln1"], cfg.norm_eps)
-        x = x + out
-        h = L.rms_norm(x, p[prefix + "ln2"], cfg.norm_eps)
-        if cfg.n_experts and not prefix:
-            moe_out, aux = moe_ffn(
-                cfg, p, h,
-                ep_axis=pg.ep_axis if self.ep > 1 else None, ep=self.ep,
+        with jax.named_scope(spans.MODEL_ATTN):
+            h = L.rms_norm(x, p[prefix + "ln1"], cfg.norm_eps)
+            h = L.gather_seq(h, tp_axis, sp)  # SP: gather seq for attention
+            attn_cfg = _AttnView(cfg, prefix)
+            out, new_cache = L.attention(
+                attn_cfg, p, h, q_pos=q_pos, cache=cache,
+                cache_index=cache_index, window=window, tp_axis=tp_axis,
+                tp=self.tp, prefix=prefix, sp=sp,
             )
             if cfg.post_norms:
-                moe_out = L.rms_norm(moe_out, p[prefix + "post_ln2"], cfg.norm_eps)
-            return x + moe_out, new_cache, aux
-        h = L.gather_seq(h, tp_axis, sp)
-        out = L.mlp(cfg, p, h, tp_axis=tp_axis, prefix=prefix, sp=sp)
-        if cfg.post_norms:
-            out = L.rms_norm(out, p[prefix + "post_ln2"], cfg.norm_eps)
-        return x + out, new_cache, 0.0
+                out = L.rms_norm(out, p[prefix + "post_ln1"], cfg.norm_eps)
+            x = x + out
+        with jax.named_scope(spans.MODEL_MLP):
+            h = L.rms_norm(x, p[prefix + "ln2"], cfg.norm_eps)
+            if cfg.n_experts and not prefix:
+                moe_out, aux = moe_ffn(
+                    cfg, p, h,
+                    ep_axis=pg.ep_axis if self.ep > 1 else None, ep=self.ep,
+                )
+                if cfg.post_norms:
+                    moe_out = L.rms_norm(moe_out, p[prefix + "post_ln2"],
+                                         cfg.norm_eps)
+                return x + moe_out, new_cache, aux
+            h = L.gather_seq(h, tp_axis, sp)
+            out = L.mlp(cfg, p, h, tp_axis=tp_axis, prefix=prefix, sp=sp)
+            if cfg.post_norms:
+                out = L.rms_norm(out, p[prefix + "post_ln2"], cfg.norm_eps)
+            return x + out, new_cache, 0.0
 
     def _cross_block(self, p, x, memory, pg):
         cfg = self.cfg
         tp_axis = pg.tp_axis if self.tp > 1 else None
-        out = L.cross_attention(cfg, p, x, memory, tp_axis=tp_axis, tp=self.tp)
-        x = x + jnp.tanh(p["x_gate"].astype(x.dtype)) * out
-        h = L.rms_norm(x, p["c_ln2"], cfg.norm_eps)
-        out = L.mlp(cfg, p, h, tp_axis=tp_axis, prefix="c_")
-        return x + jnp.tanh(p["c_gate"].astype(x.dtype)) * out
+        with jax.named_scope(spans.MODEL_ATTN):
+            out = L.cross_attention(cfg, p, x, memory, tp_axis=tp_axis,
+                                    tp=self.tp)
+            x = x + jnp.tanh(p["x_gate"].astype(x.dtype)) * out
+        with jax.named_scope(spans.MODEL_MLP):
+            h = L.rms_norm(x, p["c_ln2"], cfg.norm_eps)
+            out = L.mlp(cfg, p, h, tp_axis=tp_axis, prefix="c_")
+            return x + jnp.tanh(p["c_gate"].astype(x.dtype)) * out
 
     def _scan_groups(self):
         names = ["layers"]
@@ -323,12 +331,13 @@ class DecoderLM:
         g = pg.globals("globals")
         vstart = 0
         tp_axis = pg.tp_axis if self.tp > 1 else None
-        if self.tp > 1:
-            vstart = L.axis_index(pg.tp_axis) * g["emb"].shape[0]
-        x = L.embed(tokens, g["emb"].astype(pg.compute_dtype),
-                    tp_axis=None, vocab_start=vstart)
-        if self.tp > 1:
-            x = L.reduce_out(x, tp_axis, sp)  # SP: fused reduce-scatter(seq)
+        with jax.named_scope(spans.MODEL_EMBED):
+            if self.tp > 1:
+                vstart = L.axis_index(pg.tp_axis) * g["emb"].shape[0]
+            x = L.embed(tokens, g["emb"].astype(pg.compute_dtype),
+                        tp_axis=None, vocab_start=vstart)
+            if self.tp > 1:
+                x = L.reduce_out(x, tp_axis, sp)  # SP: reduce-scatter(seq)
         return x, g, vstart
 
     def _logits(self, pg, g, x, sp=False):
@@ -351,26 +360,27 @@ class DecoderLM:
             memory = memory.astype(pg.compute_dtype)
         x, aux, _ = self._backbone(pg, x, q_pos, memory=memory, sp=sp)
         tp_axis = pg.tp_axis if self.tp > 1 else None
-        if cfg.ce_chunk:
-            # §Perf beyond-paper: vocab-chunked online-logsumexp CE -- never
-            # materializes the (B, T, V) fp32 logits buffer
-            x = L.gather_seq(x, tp_axis, sp)
-            x = L.rms_norm(x, g["final_ln"], cfg.norm_eps)
-            head = g["emb"].T if cfg.tie_embeddings else g["head"]
-            nll, w = L.chunked_ce(
-                x[:, :-1], head.astype(pg.compute_dtype), tokens[:, 1:],
-                jnp.ones((B, T - 1), jnp.float32),
-                vocab_chunk=cfg.ce_chunk, softcap=cfg.final_softcap,
-                tp_axis=tp_axis, vocab_start=vstart,
-            )
-        else:
-            logits = self._logits(pg, g, x, sp=sp)
-            nll, w = L.vocab_parallel_ce(
-                logits[:, :-1], tokens[:, 1:],
-                jnp.ones((B, T - 1), jnp.float32),
-                tp_axis=tp_axis, vocab_start=vstart,
-            )
-        return nll + aux * w / max(cfg.n_layers, 1), w
+        with jax.named_scope(spans.MODEL_HEAD_LOSS):
+            if cfg.ce_chunk:
+                # §Perf beyond-paper: vocab-chunked online-logsumexp CE --
+                # never materializes the (B, T, V) fp32 logits buffer
+                x = L.gather_seq(x, tp_axis, sp)
+                x = L.rms_norm(x, g["final_ln"], cfg.norm_eps)
+                head = g["emb"].T if cfg.tie_embeddings else g["head"]
+                nll, w = L.chunked_ce(
+                    x[:, :-1], head.astype(pg.compute_dtype), tokens[:, 1:],
+                    jnp.ones((B, T - 1), jnp.float32),
+                    vocab_chunk=cfg.ce_chunk, softcap=cfg.final_softcap,
+                    tp_axis=tp_axis, vocab_start=vstart,
+                )
+            else:
+                logits = self._logits(pg, g, x, sp=sp)
+                nll, w = L.vocab_parallel_ce(
+                    logits[:, :-1], tokens[:, 1:],
+                    jnp.ones((B, T - 1), jnp.float32),
+                    tp_axis=tp_axis, vocab_start=vstart,
+                )
+            return nll + aux * w / max(cfg.n_layers, 1), w
 
     def cache_window(self, seq_len: int) -> int:
         """Ring-buffer size.  Long-context decode on a sliding-window arch

@@ -23,6 +23,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import spans
+
 
 def device_linear_index(runtime, layout):
     """This device's shard index within the group's FSDP axes (0..m-1)."""
@@ -33,6 +35,7 @@ def device_linear_index(runtime, layout):
     return idx
 
 
+@jax.named_scope(spans.OPTIM_WD_MASK)
 def matrix_mask_local(runtime, layout, local_shape):
     """(local_shape) 0/1 mask: 1 where the flat position belongs to a >=2-D
     tensor (weight-decay / Muon eligible).  Computed from plan intervals and
