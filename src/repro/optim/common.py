@@ -35,28 +35,57 @@ def device_linear_index(runtime, layout):
     return idx
 
 
+def matrix_runs(placements, shard_size: int, num_shards: int) -> np.ndarray:
+    """(num_shards, R, 2) int32 table of each shard's matrix runs.
+
+    The placements of rank >= 2, in offset order, merge into runs of
+    adjacent matrices (a run ends where the next matrix does not start);
+    row ``d`` holds those runs clipped to shard ``d``'s interval
+    ``[d*S, (d+1)*S)`` as local ``[lo, hi)`` pairs in ``[0, S]``, padded
+    with empty ``(0, 0)`` runs to the longest row.  Global offsets stay
+    Python ints, so groups past 2^31 elements do not overflow."""
+    runs: list[list[int]] = []
+    for pl in sorted(placements, key=lambda p: p.offset):
+        if len(pl.spec.shape) < 2:
+            continue
+        if runs and runs[-1][1] == pl.offset:
+            runs[-1][1] = pl.end
+        else:
+            runs.append([pl.offset, pl.end])
+    S = shard_size
+    rows = [[(max(a, d * S) - d * S, min(b, (d + 1) * S) - d * S)
+             for a, b in runs if a < (d + 1) * S and b > d * S]
+            for d in range(num_shards)]
+    table = np.zeros((num_shards, max(map(len, rows), default=0), 2),
+                     np.int32)
+    for d, row in enumerate(rows):
+        table[d, :len(row)] = np.reshape(row, (-1, 2))
+    return table
+
+
 @jax.named_scope(spans.OPTIM_WD_MASK)
 def matrix_mask_local(runtime, layout, local_shape):
     """(local_shape) 0/1 mask: 1 where the flat position belongs to a >=2-D
-    tensor (weight-decay / Muon eligible).  Computed from plan intervals and
-    the device index; O(#tensors) vector ops.
+    tensor (weight-decay / Muon eligible).
 
-    Global offsets can exceed int32 (multi-billion-element groups), so the
-    comparison runs in (128-lane block, within-block) coordinates: block
-    indices stay < total/128 < 2^31 for any realistic group."""
-    S = layout.plan.shard_size  # multiple of LANE=128 by planner g_coll
-    dev = device_linear_index(runtime, layout)
-    blk = dev * (S // 128) + jnp.arange(S, dtype=jnp.int32) // 128
-    within = jnp.arange(S, dtype=jnp.int32) % 128
-
-    def ge(off: int):  # global_pos >= off
-        ob, orem = off // 128, off % 128
-        return (blk > ob) | ((blk == ob) & (within >= orem))
-
-    mask = jnp.zeros((S,), jnp.float32)
-    for pl in layout.plan.placements:
-        if len(pl.spec.shape) >= 2:
-            mask = jnp.where(ge(pl.offset) & ~ge(pl.end), 1.0, mask)
+    Built from this device's row of ``matrix_runs``: about 4 vector ops per
+    run of adjacent matrices, in local coordinates (< S < 2^31), on the
+    dense ``(S/128, 128)`` view of the shard."""
+    S = layout.plan.shard_size
+    sizes = dict(zip(runtime.mesh.axis_names, runtime.mesh.devices.shape))
+    m = int(np.prod([sizes[a] for a in layout.fsdp_axes]))
+    table = matrix_runs(layout.plan.placements, S, m)
+    runs = jnp.asarray(table)[device_linear_index(runtime, layout)]
+    # the ragged planner's g_coll makes S a multiple of 128; the baseline
+    # planners' S need not be, and those shards stay one row
+    lane = 128 if S % 128 == 0 else S
+    view = (S // lane, lane)
+    pos = (lax.broadcasted_iota(jnp.int32, view, 0) * lane
+           + lax.broadcasted_iota(jnp.int32, view, 1))
+    inside = jnp.zeros(view, bool)
+    for k in range(table.shape[1]):
+        inside = inside | ((pos >= runs[k, 0]) & (pos < runs[k, 1]))
+    mask = inside.astype(jnp.float32).reshape(S)
     # broadcast to (L, S) etc.
     while mask.ndim < len(local_shape):
         mask = mask[None]
