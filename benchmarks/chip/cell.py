@@ -91,11 +91,21 @@ def program_config(conf: dict, wl: dict):
             "n_experts": conf.get("num_local_experts", 0),
             "sliding_window": None, "attn_softcap": None,
             "final_softcap": None, "post_norms": False}
+    if want["n_experts"]:
+        want.update(top_k=conf["num_experts_per_tok"],
+                    capacity_factor=conf["capacity_factor"],
+                    moe_aux_coef=conf["router_aux_loss_coef"])
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise ValueError(f"program config {cfg.name} differs from "
                          f"{conf['name']}: {got} != {want}")
     return cfg
+
+
+def token_groups(wl: dict) -> int:
+    """Groups of tokens that the step routes apart: one a chip and
+    microbatch."""
+    return wl["chips"] * wl["microbatches"]
 
 
 def _process_age() -> float:
@@ -178,14 +188,19 @@ class Program:
 
     def first_steps(self, params, opt_state, pool, seed: int, steps: int):
         """Steps ``0 .. steps-1`` through ``train_loop`` on pool batches
-        ``0 .. steps-1``; returns (params, opt_state, readings)."""
+        ``0 .. steps-1``; returns (params, opt_state, readings).  Where the
+        step reports its expert choices (``metrics["routing"]``), the
+        readings keep a host copy of each step's (``routing``)."""
         from repro.launch.train import train_loop
 
-        losses, grad, change = [], [None], [None]
+        losses, grad, change, routing = [], [None], [None], []
         key = weights.base_key(seed)
 
         def on_step(i, params, opt_state, metrics):
             losses.append(metrics["loss"])
+            if "routing" in metrics:
+                routing.append({k: np.asarray(metrics["routing"][k])
+                                for k in ("experts", "kept")})
             if i == 0:
                 grad[0] = self.grad_norms(opt_state["m"])
             if i == steps - 1:
@@ -199,6 +214,8 @@ class Program:
         readings = {"loss": [float(x) for x in losses],
                     "grad": jax.tree.map(np.asarray, grad[0]),
                     "change": jax.tree.map(np.asarray, change[0])}
+        if routing:
+            readings["routing"] = routing
         return params, opt_state, readings
 
     def hbm_plan_bytes(self) -> int:
@@ -389,6 +406,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     n_check = wl["check_steps"]
     params, opt_state, got = prog.first_steps(params, opt_state, pool, seed,
                                               n_check)
+    check.require_routing(conf, wl["limits"], got)
     setup_s = time.perf_counter() - t_start
     log(f"setup_s {setup_s!r}")
 
@@ -430,9 +448,9 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     prog.compiled = None
     gc.collect()
 
-    ref = check.Reference(conf, wl["optimizer"])
+    ref = check.Reference(conf, wl["optimizer"], groups=token_groups(wl))
     t = time.perf_counter()
-    want = ref.run(seed, pool[:n_check])
+    want = ref.run(seed, pool[:n_check], routing=got.get("routing"))
     log(f"reference s {time.perf_counter() - t!r}")
     g = check.gaps(got, want)
     ok, checks = check.judge(g, wl["limits"])

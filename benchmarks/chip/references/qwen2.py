@@ -113,7 +113,9 @@ def _attention(m: Model, p, h, mode):
     B, T, _ = h.shape
 
     def proj(name, heads):
-        y = _mm("btd,dx->btx", h, p[name], mode) + p[name + "_b"]
+        y = _mm("btd,dx->btx", h, p[name], mode)
+        if name + "_b" in p:
+            y = y + p[name + "_b"]
         return y.reshape(B, T, heads, m.hd)
 
     q = _rope(proj("wq", m.H), m.theta)

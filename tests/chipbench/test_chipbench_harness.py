@@ -24,7 +24,8 @@ def test_every_benchmark_entry_has_its_file():
         assert wl["config"] == w["config"] == conf["name"]
         assert wl["traffic"] == w["traffic"] and wl["chips"] == w["chips"]
         assert set(wl["limits"]) <= {"loss_gap", "grad_gap", "change_gap",
-                                     "grad_median_gap"}
+                                     "grad_median_gap", "route_gap",
+                                     "kept_gap", "dropped_share"}
     for c in BENCH["configs"]:
         conf = json.loads((ROOT / c["file"]).read_text())
         assert conf["name"] == c["name"] and conf["reduced"] == c["reduced"]
