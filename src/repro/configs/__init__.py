@@ -20,6 +20,8 @@ _MODULES = {
     # the paper's own evaluation models
     "llama3-70b": "llama3_70b",
     "gpt-oss-120b": "gpt_oss_120b",
+    # published models added for the chip benchmark
+    "granite-3.0-1b-a400m": "granite_3_0_1b_a400m",
 }
 
 ARCH_IDS = list(_MODULES)

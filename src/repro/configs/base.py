@@ -127,7 +127,18 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     moe_aux_coef: float = 0.01
-    capacity_factor: float = 1.25
+    # None: dropless -- every choice runs, experts take uneven groups
+    capacity_factor: Optional[float] = 1.25
+    # load-balance term E * sum_e P_e * f_e with f_e each expert's share of
+    # the choices ("switch") or of the tokens, k times that ("mixtral": hf
+    # load_balancing_loss_func)
+    moe_aux_form: str = "switch"
+
+    # --- scalar multipliers (granite); None leaves the path as it is -------
+    embedding_multiplier: Optional[float] = None  # token embeddings times it
+    attention_multiplier: Optional[float] = None  # replaces 1/sqrt(head_dim)
+    residual_multiplier: Optional[float] = None   # each block branch times it
+    logits_scaling: Optional[float] = None        # LM-head logits over it
 
     # --- vlm (stub vision frontend: input_specs provides patch embeddings) --
     cross_attn_interval: int = 0  # every k-th layer is a cross-attn layer

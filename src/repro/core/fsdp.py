@@ -475,9 +475,15 @@ class FSDPRuntime:
     # ------------------------------------------------------------------ #
     def make_train_step(self, optimizer) -> Callable:
         """optimizer: repro.optim.* object with init(layouts, params) and
-        update(runtime, params, grads, state, step)."""
+        update(runtime, params, grads, state, step).
+
+        The step's metrics are ``loss``, ``tokens`` and ``grad_norm``; a
+        model that ``reports_routing`` adds ``routing``: ``experts`` and
+        ``kept``, each (moe_layers, tokens, top_k) in the batch's token
+        order, from the model's loss report."""
         par = self.cfg.parallel
         pspecs = self._param_specs()
+        routed = getattr(self.model, "reports_routing", False)
 
         # groups whose reduce wire runs error feedback: their trainable
         # tree carries the residual, whose "gradient" is the updated
@@ -558,23 +564,28 @@ class FSDPRuntime:
                     bufs = {n: self.layouts[n].store.combine(tr[n], frozen[n])
                             for n in tr}
                     pg = self._getter(bufs, defer_ef=defer)
-                    nll, w = self.model.loss(pg, mb)
-                    return nll, w
+                    nll, w, report = self.model.loss(pg, mb)
+                    return nll, (w, report)
 
                 if micro > 1:
                     def micro_body(acc, mb):
                         grads, nll_a, w_a = acc
-                        (nll, w), g = jax.value_and_grad(
+                        (nll, (w, report)), g = jax.value_and_grad(
                             loss_of, has_aux=True)(trainable, mb)
                         grads = jax.tree.map(jnp.add, grads, g)
-                        return (grads, nll_a + nll, w_a + w), None
+                        return (grads, nll_a + nll, w_a + w), report
 
                     mbs = jax.tree.map(
                         lambda t: t.reshape((micro, t.shape[0] // micro)
                                             + t.shape[1:]), batch)
                     zero = jax.tree.map(jnp.zeros_like, trainable)
-                    (grads, nll, w), _ = lax.scan(
+                    (grads, nll, w), reports = lax.scan(
                         micro_body, (zero, 0.0, 0.0), mbs)
+                    # (micro, layers, tokens, ...) -> (layers, micro *
+                    # tokens, ...): microbatches hold consecutive rows
+                    report = jax.tree.map(
+                        lambda r: jnp.moveaxis(r, 0, 1).reshape(
+                            (r.shape[1], -1) + r.shape[3:]), reports)
                     if defer:
                         grads = dict(grads)
                         cd = jnp.dtype(self.compute_dtype)
@@ -613,7 +624,7 @@ class FSDPRuntime:
                                 shard, new_ef = rs(sum_ct, ef0)
                             grads[n] = {"master": shard, EF_KEY: new_ef}
                 else:
-                    (nll, w), grads = jax.value_and_grad(
+                    (nll, (w, report)), grads = jax.value_and_grad(
                         loss_of, has_aux=True)(trainable, batch)
 
                 # the EF residuals ride back through the grad tree (their
@@ -643,14 +654,22 @@ class FSDPRuntime:
                         "tokens": w_g,
                         "grad_norm": _global_norm(self, grads),
                     }
+                if routed:
+                    metrics["routing"] = report["routing"]
                 return new_params, new_opt, metrics
 
             opt_specs = optimizer.pspecs(self)
+            batch_specs = self.batch_pspec(batch)
+            metric_specs = {"loss": P(), "tokens": P(), "grad_norm": P()}
+            if routed:
+                # each device's tokens, its rows of the batch, in order
+                rows = batch_specs["tokens"][0]
+                metric_specs["routing"] = {"experts": P(None, rows, None),
+                                           "kept": P(None, rows, None)}
             fn = shard_map(
                 sharded, mesh=self.mesh,
-                in_specs=(pspecs, opt_specs, P(), self.batch_pspec(batch)),
-                out_specs=(pspecs, opt_specs,
-                           {"loss": P(), "tokens": P(), "grad_norm": P()}),
+                in_specs=(pspecs, opt_specs, P(), batch_specs),
+                out_specs=(pspecs, opt_specs, metric_specs),
             )
             new_params, new_opt, metrics = fn(params, opt_state, step, batch)
             return new_params, new_opt, step + 1, metrics
@@ -945,8 +964,11 @@ class _ParamGetter:
 
         # activation-only remat: gathered buffers enter as checkpoint
         # inputs, so they are saved into backward (no re-gather)
-        inner = (jax.checkpoint(compute) if remat and not reshard
-                 else compute)
+        # what the model keeps through the checkpoint (a routed model: its
+        # routing); None recomputes everything
+        policy = getattr(self.rt.model, "remat_policy", None)
+        inner = (jax.checkpoint(compute, policy=policy)
+                 if remat and not reshard else compute)
 
         def slices(lo, hi):
             # stacks entries are store states (arrays or code/scale trees)
@@ -962,7 +984,7 @@ class _ParamGetter:
                 return inner(gather_layer(layer_bufs), c, user_xs)
 
             if remat and reshard:
-                scan_body = jax.checkpoint(scan_body)
+                scan_body = jax.checkpoint(scan_body, policy=policy)
             length = hi - lo
             return lax.scan(scan_body, carry, slices(lo, hi), length=length,
                             unroll=max(1, min(self.rt.scan_unroll, length)))
@@ -1002,7 +1024,7 @@ class _ParamGetter:
                 return c, (y0, y1)
 
             if remat and reshard:
-                pair_body = jax.checkpoint(pair_body)
+                pair_body = jax.checkpoint(pair_body, policy=policy)
             carry, (ys0, ys1) = lax.scan(
                 pair_body, carry, (pair_bufs, pair_xs), length=plan.pairs,
                 unroll=max(1, min(self.rt.scan_unroll, plan.pairs)))
@@ -1021,7 +1043,7 @@ class _ParamGetter:
             # gathered params are saved into backward (first to be needed
             # there), skipping one re-gather, as in FSDP2's skip-reshard-
             # last-block policy; activations still remat
-            last_inner = jax.checkpoint(compute)
+            last_inner = jax.checkpoint(compute, policy=policy)
 
             def last_body(c, scan_xs):
                 layer_bufs, user_xs = scan_xs

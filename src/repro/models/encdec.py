@@ -127,7 +127,7 @@ class EncDecModel:
         logits = L.lm_logits(x, g["head"])
         nll, w = L.vocab_parallel_ce(
             logits[:, :-1], tokens[:, 1:], jnp.ones((B, T - 1), jnp.float32))
-        return nll, w
+        return nll, w, {}
 
     def cache_shapes(self, batch: int, seq_len: int) -> dict[str, Any]:
         cfg = self.cfg

@@ -117,11 +117,13 @@ def chunked_attention(
     window=None,      # int | traced scalar | None
     softcap=None,
     chunk: int = 1024,
+    scale=None,       # score multiplier (None -> 1 / sqrt(hd))
 ):
     B, Hq, Tq, hd = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     group = Hq // Hkv
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     chunk = min(chunk, Tk)
     nc = -(-Tk // chunk)
@@ -261,11 +263,12 @@ def attention(
     k = rope(k, q_pos, cfg.rope_theta)
 
     chunk = getattr(cfg, "attn_chunk", 1024)
+    scale = getattr(cfg, "attention_multiplier", None)
     if cache is None:
         out = chunked_attention(
             q, k, v, q_pos=q_pos if causal else None,
             kv_pos=q_pos if causal else None, window=window,
-            softcap=cfg.attn_softcap, chunk=chunk,
+            softcap=cfg.attn_softcap, chunk=chunk, scale=scale,
         )
         new_cache = None
     else:
@@ -297,6 +300,7 @@ def attention(
         out = chunked_attention(
             q, ck, cv, q_pos=q_pos, kv_pos=cpos, kv_valid=valid,
             window=window, softcap=cfg.attn_softcap, chunk=chunk,
+            scale=scale,
         )
         new_cache = {"k": ck, "v": cv, "pos": cpos}
 
@@ -353,15 +357,19 @@ def embed(tokens, emb_local, *, tp_axis=None, vocab_start=0):
     return psum(x, tp_axis)
 
 
-def lm_logits(x, head_local, *, softcap=None):
+def lm_logits(x, head_local, *, softcap=None, scaling=None):
+    """``x @ head``, over ``scaling`` where given (granite's
+    ``logits_scaling``), then soft-capped where given."""
     logits = x @ head_local.astype(x.dtype)
+    if scaling is not None:
+        logits = logits / scaling
     if softcap is not None:
         logits = softcap * jnp.tanh(logits / softcap)
     return logits
 
 
 def chunked_ce(x, head, labels, mask, *, vocab_chunk: int = 8192,
-               softcap=None, tp_axis=None, vocab_start=0):
+               softcap=None, tp_axis=None, vocab_start=0, scaling=None):
     """Cross entropy without materializing (B, T, V) logits: scan over vocab
     chunks with an online max/logsumexp (the lm-head analogue of flash
     attention).  Beyond-paper §Perf optimization: the fp32 logits buffer for
@@ -383,6 +391,8 @@ def chunked_ce(x, head, labels, mask, *, vocab_chunk: int = 8192,
         m, s, picked = carry
         h_i, ci = xs
         lg = (x32 @ h_i.astype(x.dtype)).astype(jnp.float32)
+        if scaling is not None:
+            lg = lg / scaling
         if softcap is not None:
             lg = softcap * jnp.tanh(lg / softcap)
         base = ci * vocab_chunk

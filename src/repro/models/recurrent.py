@@ -99,7 +99,7 @@ class XLSTMModel:
         logits = L.lm_logits(x, g["head"])
         nll, w = L.vocab_parallel_ce(
             logits[:, :-1], tokens[:, 1:], jnp.ones((B, T - 1), jnp.float32))
-        return nll, w
+        return nll, w, {}
 
     def cache_shapes(self, batch: int, seq_len: int) -> dict[str, Any]:
         cfg = self.cfg
@@ -285,7 +285,7 @@ class HymbaModel:
         logits = L.lm_logits(x, g["head"])
         nll, w = L.vocab_parallel_ce(
             logits[:, :-1], tokens[:, 1:], jnp.ones((B, T - 1), jnp.float32))
-        return nll, w
+        return nll, w, {}
 
     def cache_window(self, seq_len: int) -> int:
         if self.cfg.sliding_window and seq_len > 65536:

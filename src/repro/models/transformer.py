@@ -21,7 +21,7 @@ from jax import lax
 from .. import spans
 from ..core.ragged import ShardDim, TensorSpec
 from . import layers as L
-from .moe import moe_ffn
+from .moe import ROUTING, moe_ffn
 
 
 # ---------------------------------------------------------------------------
@@ -78,6 +78,22 @@ class DecoderLM:
         else:
             self.n_blocks = cfg.n_layers
             self.selfs_per_block = 1
+        #: layers whose MLP routes tokens to experts (a VLM's prefixed
+        #: self-layers run the dense MLP)
+        self.moe_layers = (self.n_blocks if cfg.n_experts and not self.is_vlm
+                           else 0)
+        #: the train step's metrics carry the routing (``loss``'s report)
+        #: of a dropless model; the capacity path reports none yet (the
+        #: tiny routed cell of ``tests/chipbench`` pins it so, and reads its
+        #: choices from outside through ``routing_report.py``)
+        self.reports_routing = (bool(self.moe_layers)
+                                and cfg.capacity_factor is None)
+        #: the layer scan's remat policy: a routed model keeps its routing
+        #: (``moe.ROUTING``) so the recomputed forward routes as the
+        #: forward did; None recomputes everything
+        self.remat_policy = (
+            jax.checkpoint_policies.save_only_these_names(ROUTING)
+            if self.moe_layers else None)
 
     # ---------------- specs ------------------------------------------------
     def _self_layer_specs(self, prefix=""):
@@ -227,8 +243,16 @@ class DecoderLM:
             w = [big] * cfg.n_layers
         return jnp.asarray(w, jnp.int32)
 
+    def _residual(self, x, out):
+        """``x + out``, the branch times ``residual_multiplier`` where
+        given."""
+        if self.cfg.residual_multiplier is not None:
+            out = out * self.cfg.residual_multiplier
+        return x + out
+
     def _self_block(self, p, x, q_pos, window, cache=None, cache_index=0,
                     pg=None, prefix="", sp=False):
+        """(x, new cache, load-balance term, routing or None)."""
         cfg = self.cfg
         tp_axis = pg.tp_axis if self.tp > 1 else None
         with jax.named_scope(spans.MODEL_ATTN):
@@ -242,23 +266,23 @@ class DecoderLM:
             )
             if cfg.post_norms:
                 out = L.rms_norm(out, p[prefix + "post_ln1"], cfg.norm_eps)
-            x = x + out
+            x = self._residual(x, out)
         with jax.named_scope(spans.MODEL_MLP):
             h = L.rms_norm(x, p[prefix + "ln2"], cfg.norm_eps)
             if cfg.n_experts and not prefix:
-                moe_out, aux = moe_ffn(
+                moe_out, aux, routing = moe_ffn(
                     cfg, p, h,
                     ep_axis=pg.ep_axis if self.ep > 1 else None, ep=self.ep,
                 )
                 if cfg.post_norms:
                     moe_out = L.rms_norm(moe_out, p[prefix + "post_ln2"],
                                          cfg.norm_eps)
-                return x + moe_out, new_cache, aux
+                return self._residual(x, moe_out), new_cache, aux, routing
             h = L.gather_seq(h, tp_axis, sp)
             out = L.mlp(cfg, p, h, tp_axis=tp_axis, prefix=prefix, sp=sp)
             if cfg.post_norms:
                 out = L.rms_norm(out, p[prefix + "post_ln2"], cfg.norm_eps)
-            return x + out, new_cache, 0.0
+            return self._residual(x, out), new_cache, 0.0, None
 
     def _cross_block(self, p, x, memory, pg):
         cfg = self.cfg
@@ -282,7 +306,10 @@ class DecoderLM:
 
     def _backbone(self, pg, x, q_pos, memory=None, caches=None,
                   cache_index=0, sp=False):
-        """Run the layer stack.  caches: pytree with leading dim n_blocks."""
+        """Run the layer stack.  caches: pytree with leading dim n_blocks.
+        Returns (x, load-balance term summed over layers, new caches,
+        each layer's routing where the model ``reports_routing``, else
+        None)."""
         cfg = self.cfg
         windows = self._layer_windows().reshape(
             self.n_blocks, self.selfs_per_block
@@ -292,18 +319,20 @@ class DecoderLM:
         def body(p, carry, xs):
             x, aux = carry
             win, cache = xs
-            new_caches = []
+            new_caches, routing = [], None
             for i in range(self.selfs_per_block):
                 pre = f"s{i}_" if self.is_vlm else ""
                 c_i = None if cache is None else jax.tree.map(
                     lambda t, i=i: t[i], cache)
-                x, nc, a = self._self_block(
+                x, nc, a, r = self._self_block(
                     p, x, q_pos, win[i], cache=c_i, cache_index=cache_index,
                     pg=pg, prefix=pre, sp=sp,
                 )
                 aux = aux + a
                 if nc is not None:
                     new_caches.append(nc)
+                if r is not None:
+                    routing = r
             if self.is_vlm and memory is not None:
                 x = self._cross_block(p, x, memory, pg)
             y = (
@@ -311,12 +340,15 @@ class DecoderLM:
                 if new_caches
                 else None
             )
+            if self.reports_routing:
+                y = (y, routing)
             return (x, aux), y
 
         xs = (windows, caches)
-        (x, aux), new_caches = pg.scan(self._scan_groups(), body,
-                                       (x, jnp.float32(0)), xs)
-        return x, aux, new_caches
+        (x, aux), ys = pg.scan(self._scan_groups(), body,
+                               (x, jnp.float32(0)), xs)
+        new_caches, routing = ys if self.reports_routing else (ys, None)
+        return x, aux, new_caches, routing
 
     def _sp_active(self, T: int) -> bool:
         """Sequence parallelism: residual stream seq-sharded over the TP
@@ -336,6 +368,8 @@ class DecoderLM:
                 vstart = L.axis_index(pg.tp_axis) * g["emb"].shape[0]
             x = L.embed(tokens, g["emb"].astype(pg.compute_dtype),
                         tp_axis=None, vocab_start=vstart)
+            if cfg.embedding_multiplier is not None:
+                x = x * cfg.embedding_multiplier
             if self.tp > 1:
                 x = L.reduce_out(x, tp_axis, sp)  # SP: reduce-scatter(seq)
         return x, g, vstart
@@ -345,10 +379,15 @@ class DecoderLM:
         x = L.gather_seq(x, pg.tp_axis if self.tp > 1 else None, sp)
         x = L.rms_norm(x, g["final_ln"], cfg.norm_eps)
         head = g["emb"].T if cfg.tie_embeddings else g["head"]
-        return L.lm_logits(x, head, softcap=cfg.final_softcap)
+        return L.lm_logits(x, head, softcap=cfg.final_softcap,
+                           scaling=cfg.logits_scaling)
 
     # ---------------- public API ----------------------------------------------
     def loss(self, pg, batch):
+        """(summed token NLL plus the load-balance term, token weight,
+        report): a model that ``reports_routing`` reports ``routing``,
+        ``experts`` and ``kept`` each (moe_layers, B * T, k) in the batch's
+        token order."""
         cfg = self.cfg
         tokens = batch["tokens"]
         B, T = tokens.shape
@@ -358,7 +397,9 @@ class DecoderLM:
         memory = batch.get("patches") if self.is_vlm else None
         if memory is not None:
             memory = memory.astype(pg.compute_dtype)
-        x, aux, _ = self._backbone(pg, x, q_pos, memory=memory, sp=sp)
+        x, aux, _, routing = self._backbone(pg, x, q_pos, memory=memory,
+                                            sp=sp)
+        report = {} if routing is None else {"routing": routing}
         tp_axis = pg.tp_axis if self.tp > 1 else None
         with jax.named_scope(spans.MODEL_HEAD_LOSS):
             if cfg.ce_chunk:
@@ -372,6 +413,7 @@ class DecoderLM:
                     jnp.ones((B, T - 1), jnp.float32),
                     vocab_chunk=cfg.ce_chunk, softcap=cfg.final_softcap,
                     tp_axis=tp_axis, vocab_start=vstart,
+                    scaling=cfg.logits_scaling,
                 )
             else:
                 logits = self._logits(pg, g, x, sp=sp)
@@ -380,7 +422,7 @@ class DecoderLM:
                     jnp.ones((B, T - 1), jnp.float32),
                     tp_axis=tp_axis, vocab_start=vstart,
                 )
-            return nll + aux * w / max(cfg.n_layers, 1), w
+            return nll + aux * w / max(cfg.n_layers, 1), w, report
 
     def cache_window(self, seq_len: int) -> int:
         """Ring-buffer size.  Long-context decode on a sliding-window arch
@@ -433,7 +475,7 @@ class DecoderLM:
         memory = batch.get("patches") if self.is_vlm else None
         if memory is not None:
             memory = memory.astype(pg.compute_dtype)
-        x, _, new_cache = self._backbone(
+        x, _, new_cache, _ = self._backbone(
             pg, x, q_pos, memory=memory, caches=cache, cache_index=0, sp=sp)
         x = L.gather_seq(x, pg.tp_axis if self.tp > 1 else None, sp)
         logits = self._logits(pg, g, x[:, -1:])
@@ -453,7 +495,7 @@ class DecoderLM:
         memory = batch.get("patches") if self.is_vlm else None
         if memory is not None:
             memory = memory.astype(pg.compute_dtype)
-        x, _, new_cache = self._backbone(
+        x, _, new_cache, _ = self._backbone(
             pg, x, q_pos, memory=memory, caches=cache, cache_index=index)
         logits = self._logits(pg, g, x)
         return logits, new_cache

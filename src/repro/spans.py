@@ -7,7 +7,7 @@ for work recomputed in the backward, after ``checkpoint/
 rematted_computation``, so device time splits by program layer, pass and
 recompute.  The four ``model.*`` scopes, the three ``fsdp.*`` scopes and
 ``optim.update`` never nest in one another; ``optim.wd_mask`` nests in
-``optim.update``.
+``optim.update``, and the ``model.moe.*`` scopes in ``model.mlp``.
 
 Host spans are ``jax.profiler`` annotations in ``launch.train.
 train_loop``: ``TraceMe``s that cost nothing while no profiler is
@@ -40,6 +40,21 @@ OPTIM_WD_MASK = "optim.wd_mask"
 DEVICE_SCOPES = (MODEL_EMBED, MODEL_ATTN, MODEL_MLP, MODEL_HEAD_LOSS,
                  FSDP_GATHER, FSDP_UNPACK, FSDP_GRAD_SYNC, OPTIM_UPDATE,
                  OPTIM_WD_MASK)
+
+# A routed MLP's parts (``models/moe.moe_ffn``), each nested in
+# ``model.mlp`` and none in another.
+#: router matmul, softmax, top-k and the load-balance statistics
+MOE_ROUTE = "model.moe.route"
+#: choices into expert order (sort, group counts, gather; or the capacity
+#: ranks and buffer scatter), back to token order, and the gate-weighted
+#: combine
+MOE_DISPATCH = "model.moe.dispatch"
+#: the expert matmuls (grouped over uneven groups, or over padded slots)
+#: and their SwiGLU.  XLA:TPU rewrites each grouped matmul into kernels
+#: that carry their own ``op_name`` (``ragged-dot-*``), not this scope
+MOE_EXPERTS = "model.moe.experts"
+
+MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS)
 
 #: one iteration of ``train_loop`` (a ``StepTraceAnnotation``, which also
 #: gives XProf its steps)

@@ -238,6 +238,7 @@ def test_moe_capacity_drops_and_combines():
         top_k = 2
         capacity_factor = 1.0
         moe_aux_coef = 0.01
+        moe_aux_form = "switch"
         mlp = "swiglu"
 
     B, T, D, F, E = 2, 8, 16, 32, 4
@@ -248,15 +249,81 @@ def test_moe_capacity_drops_and_combines():
         "moe_w2": rnd((E, F, D), 4) * 0.2,
     }
     x = rnd((B, T, D), 5)
-    out, aux = moe_ffn(Cfg, p, x)
+    out, aux, _ = moe_ffn(Cfg, p, x)
     assert out.shape == x.shape
     assert np.isfinite(np.asarray(out)).all()
     assert float(aux) > 0
     # zero capacity factor edge: everything dropped -> output ~0
     Cfg.capacity_factor = 1e-9
-    out0, _ = moe_ffn(Cfg, p, x)
+    out0, _, _ = moe_ffn(Cfg, p, x)
     # cap >= 1 always, so at most E tokens survive; most are dropped
     assert np.abs(np.asarray(out0)).mean() < np.abs(np.asarray(out)).mean()
+
+
+def _dense_moe(p, x, experts, gates, E):
+    """Every expert on every token, weighted by its gate or by nought."""
+    N = experts.shape[0]
+    combine = jnp.zeros((N, E), jnp.float32).at[
+        jnp.arange(N)[:, None], experts].add(gates)
+    g = jnp.einsum("nd,edf->nef", x, p["moe_w1"], precision="highest")
+    u = jnp.einsum("nd,edf->nef", x, p["moe_w3"], precision="highest")
+    act = jax.nn.silu(g) * u * combine[..., None]
+    return jnp.einsum("nef,efd->nd", act, p["moe_w2"], precision="highest")
+
+
+def test_moe_dropless_grouped_matches_dense_form():
+    """The dropless path (sort into expert order, grouped matmuls over
+    uneven groups, back to token order, gate-weighted combine) equals
+    every expert run on every token weighted by its gate, in float32,
+    value and gradient, with uneven groups and one expert chosen by no
+    token."""
+    class Cfg:
+        n_experts = 6
+        top_k = 2
+        capacity_factor = None
+        moe_aux_coef = 0.001
+        moe_aux_form = "mixtral"
+
+    B, T, D, F, E = 2, 12, 16, 8, Cfg.n_experts
+    p = {"moe_router": rnd((D, E), 1),
+         "moe_w1": rnd((E, D, F), 2) * 0.3,
+         "moe_w3": rnd((E, D, F), 3) * 0.3,
+         "moe_w2": rnd((E, F, D), 4) * 0.3}
+    # positive activations: expert 5 is chosen by no token, expert 0 by
+    # most
+    p["moe_router"] = p["moe_router"].at[:, 5].set(-5.0).at[:, 0].add(0.5)
+    x = jnp.abs(rnd((B, T, D), 5))
+
+    def routed(p, x):
+        out, aux, r = moe_ffn(Cfg, p, x)
+        return out, aux, r
+
+    out, aux, r = jax.jit(routed)(p, x)
+    sizes = np.bincount(np.asarray(r["experts"]).ravel(), minlength=E)
+    assert sizes[5] == 0 and len(set(sizes[:5].tolist())) > 1
+    assert np.asarray(r["kept"]).all() and r["experts"].dtype == jnp.int32
+
+    def dense(p, x):
+        xf = x.reshape(B * T, D)
+        logits = xf @ p["moe_router"]
+        e = r["experts"]
+        gates = jax.nn.softmax(jnp.take_along_axis(logits, e, -1), -1)
+        return _dense_moe(p, xf, e, gates, E).reshape(B, T, D)
+
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense(p, x)),
+                               rtol=1e-5, atol=1e-5)
+    ct = rnd((B, T, D), 7)
+    g_got = jax.jit(jax.grad(lambda p, x: jnp.sum(routed(p, x)[0] * ct),
+                             argnums=(0, 1)))(p, x)
+    g_want = jax.jit(jax.grad(lambda p, x: jnp.sum(dense(p, x) * ct),
+                              argnums=(0, 1)))(p, x)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+    # hf's load balance: E * sum_e P_e * f_e, f over the tokens
+    probs = jax.nn.softmax(x.reshape(B * T, D) @ p["moe_router"], -1)
+    want = E * np.sum(np.asarray(probs).mean(0) * sizes / (B * T)) * 0.001
+    np.testing.assert_allclose(float(aux), want, rtol=1e-5)
 
 
 # ---------------------------------------------------------------------------
