@@ -1,4 +1,5 @@
-"""THE dispatch layer for every quant hot path (repro.kernels).
+"""THE dispatch layer for every quant hot path (repro.kernels), and for
+the fused causal attention of ``layers.attention``.
 
 Every hot-path call site (core.wire encode/decode, core.store
 create/rebuild, the q8 reduce-scatter internals, optim.adam8bit, the serve
@@ -30,6 +31,8 @@ from .adam_update import adamw_update as _adamw
 from .blockwise_quant import (dequantize as _deq,
                               dequantize_into as _deq_into, quantize as _q)
 from .encode_ef import encode_ef as _encode_ef
+from .flash_attention import LANES as _LANES
+from .flash_attention import causal_attention as _causal_attention
 from .fused_update import (adam8bit_store_update as _adam8_store,
                            adamw_store_update as _adamw_store)
 from .q8_matmul import (QuantTensor, fold_scales, q8_matmul as _q8mm,
@@ -39,7 +42,7 @@ __all__ = [
     "quantize", "dequantize", "dequantize_into", "encode_ef", "q8_matmul",
     "quantize_log", "dequantize_log", "adamw_update", "adam8bit_update",
     "adamw_store_update", "adam8bit_store_update", "q8_slice_cols",
-    "QuantTensor", "quant_eligible", "fold_scales",
+    "QuantTensor", "quant_eligible", "fold_scales", "causal_attention",
 ]
 
 
@@ -180,3 +183,19 @@ def q8_slice_cols(qt, start, width: int):
     tensor dequantizes to exactly the sliced dequantized original.
     """
     return _q8_slice(qt, start, width)
+
+
+def causal_attention(q, k, v, *, scale: float):
+    """Fused causal self-attention over positions 0..T-1 (GQA): scores stay
+    in VMEM, KV blocks above the diagonal are skipped, and the backward
+    keeps only the output and the per-row logsumexp.  Returns None where
+    the kernel does not compile here -- off a TPU, or for a sequence its
+    128-multiple blocks do not tile (the caller falls back to
+    ``layers.chunked_attention``).
+
+    PARITY: ALLCLOSE -- vs ``layers.chunked_attention`` (bf16 MXU
+    operands, float32 softmax statistics and accumulators).
+    """
+    if _interpret() or q.shape[2] % _LANES:
+        return None
+    return _causal_attention(q, k, v, scale=scale)

@@ -120,9 +120,9 @@ class EncDecModel:
         B, T = tokens.shape
         g = pg.globals("globals")
         memory = self._encode(pg, frames, g)
-        q_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
         x = L.embed(tokens, g["emb"].astype(pg.compute_dtype))
-        x, _ = self._decode_stack(pg, x, memory, q_pos)
+        # q_pos None: the sequence's own index (layers.attention)
+        x, _ = self._decode_stack(pg, x, memory, None)
         x = L.rms_norm(x, g["final_ln"], cfg.norm_eps)
         logits = L.lm_logits(x, g["head"])
         nll, w = L.vocab_parallel_ce(

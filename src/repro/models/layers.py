@@ -7,7 +7,8 @@ Conventions:
     collectives are no-ops when it is None
   * softmax/normalizer math runs in float32 regardless of compute dtype
   * 32k-token prefill never materializes (T x T) logits: attention is chunked
-    with an online softmax (lax.scan over KV blocks)
+    with an online softmax (lax.scan over KV blocks), or on a TPU, for causal
+    self-attention, a fused flash-attention kernel (kernels/flash_attention)
 """
 from __future__ import annotations
 
@@ -220,10 +221,16 @@ def attention(
 ):
     """Self-attention with optional ring-buffer KV cache.
 
+    q_pos: (B, T) int32 positions, or None for the sequence's own index
+    0..T-1 (training and prefill).
     cache: None (training) or dict(k=(B,Hkv,W,hd), v=..., pos=(B,W) int32,
     init -1).  W may be < seq_len (sliding-window ring buffer -- how the
     long_500k decode shape stays sub-linear in memory).  Writes at
     ``cache_index % W``; validity/causality come from the stored positions.
+
+    Causal attention without a cache over index positions, with no window
+    and no softcap, runs the fused kernel (``ops.causal_attention``) where
+    it compiles; everything else runs ``chunked_attention``.
     Returns (out, new_cache)."""
     B, T, D = x.shape
     hd = cfg.hd
@@ -259,12 +266,22 @@ def attention(
     k = proj("wk", hkv, kv=True)
     v = proj("wv", hkv, kv=True)
 
+    own_index = q_pos is None
+    if own_index:
+        q_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
     q = rope(q, q_pos, cfg.rope_theta)
     k = rope(k, q_pos, cfg.rope_theta)
 
     chunk = getattr(cfg, "attn_chunk", 1024)
     scale = getattr(cfg, "attention_multiplier", None)
-    if cache is None:
+    fused = None
+    if (cache is None and causal and own_index and window is None
+            and cfg.attn_softcap is None):
+        fused = ops.causal_attention(
+            q, k, v, scale=1.0 / math.sqrt(hd) if scale is None else scale)
+    if fused is not None:
+        out, new_cache = fused, None
+    elif cache is None:
         out = chunked_attention(
             q, k, v, q_pos=q_pos if causal else None,
             kv_pos=q_pos if causal else None, window=window,

@@ -228,19 +228,20 @@ class DecoderLM:
 
     # ---------------- forward ------------------------------------------------
     def _layer_windows(self):
-        """Per-layer attention window (int32 array, big = global).  gemma2
-        alternates local(sliding)/global [arXiv:2408.00118]."""
+        """Per-layer attention window (int32 array, big = global), or None
+        where no layer has one.  gemma2 alternates local(sliding)/global
+        [arXiv:2408.00118]."""
         cfg = self.cfg
+        if not cfg.sliding_window:
+            return None
         big = np.int32(2**30)
-        if cfg.local_global_alternate and cfg.sliding_window:
+        if cfg.local_global_alternate:
             w = [
                 cfg.sliding_window if i % 2 == 0 else big
                 for i in range(cfg.n_layers)
             ]
-        elif cfg.sliding_window:
-            w = [cfg.sliding_window] * cfg.n_layers
         else:
-            w = [big] * cfg.n_layers
+            w = [cfg.sliding_window] * cfg.n_layers
         return jnp.asarray(w, jnp.int32)
 
     def _residual(self, x, out):
@@ -311,10 +312,12 @@ class DecoderLM:
         each layer's routing where the model ``reports_routing``, else
         None)."""
         cfg = self.cfg
-        windows = self._layer_windows().reshape(
-            self.n_blocks, self.selfs_per_block
-            if not self.is_vlm else cfg.cross_attn_interval
-        )[:, : self.selfs_per_block]
+        windows = self._layer_windows()
+        if windows is not None:
+            windows = windows.reshape(
+                self.n_blocks, self.selfs_per_block
+                if not self.is_vlm else cfg.cross_attn_interval
+            )[:, : self.selfs_per_block]
 
         def body(p, carry, xs):
             x, aux = carry
@@ -325,7 +328,8 @@ class DecoderLM:
                 c_i = None if cache is None else jax.tree.map(
                     lambda t, i=i: t[i], cache)
                 x, nc, a, r = self._self_block(
-                    p, x, q_pos, win[i], cache=c_i, cache_index=cache_index,
+                    p, x, q_pos, None if win is None else win[i],
+                    cache=c_i, cache_index=cache_index,
                     pg=pg, prefix=pre, sp=sp,
                 )
                 aux = aux + a
@@ -392,12 +396,12 @@ class DecoderLM:
         tokens = batch["tokens"]
         B, T = tokens.shape
         sp = self._sp_active(T)
-        q_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
         x, g, vstart = self._embed_in(pg, tokens, sp=sp)
         memory = batch.get("patches") if self.is_vlm else None
         if memory is not None:
             memory = memory.astype(pg.compute_dtype)
-        x, aux, _, routing = self._backbone(pg, x, q_pos, memory=memory,
+        # q_pos None: the sequence's own index (layers.attention)
+        x, aux, _, routing = self._backbone(pg, x, None, memory=memory,
                                             sp=sp)
         report = {} if routing is None else {"routing": routing}
         tp_axis = pg.tp_axis if self.tp > 1 else None
@@ -468,15 +472,13 @@ class DecoderLM:
     def prefill(self, pg, batch, cache):
         cfg = self.cfg
         tokens = batch["tokens"]
-        B, T = tokens.shape
-        sp = self._sp_active(T)
-        q_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+        sp = self._sp_active(tokens.shape[1])
         x, g, _ = self._embed_in(pg, tokens, sp=sp)
         memory = batch.get("patches") if self.is_vlm else None
         if memory is not None:
             memory = memory.astype(pg.compute_dtype)
         x, _, new_cache, _ = self._backbone(
-            pg, x, q_pos, memory=memory, caches=cache, cache_index=0, sp=sp)
+            pg, x, None, memory=memory, caches=cache, cache_index=0, sp=sp)
         x = L.gather_seq(x, pg.tp_axis if self.tp > 1 else None, sp)
         logits = self._logits(pg, g, x[:, -1:])
         return logits, new_cache

@@ -13,7 +13,9 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
 imports this file.  Kernel shapes are Granite's shard widths: one layer of
 the ``layers`` group (3,180,544 elements on one chip) and one sequence of
-4096 tokens; the whole-step test runs the update at all 8 layers.
+4096 tokens; the whole-step test runs the update at all 8 layers.  The
+fused causal attention compiles at both benchmark cells' heads and head
+sizes (Granite 16 / 8 of 64, Qwen2.5-14B 40 / 8 of 128).
 """
 import dataclasses
 import re
@@ -127,8 +129,46 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+ATTENTION_KERNELS = ("flash_attention_fwd", "flash_attention_dq",
+                     "flash_attention_dkv")
+#: the cells' attention: query heads, KV heads, head size, score multiplier
+ATTENTION = {"granite": (16, 8, 64, 1 / 64),
+             "qwen": (40, 8, 128, 128 ** -0.5)}
+
+
+def _kernel_calls(text: str, kernel: str) -> list[str]:
+    """The compiled program's lines that define a call of ``kernel``."""
+    return [line for line in text.splitlines()
+            if re.match(rf"\s*(ROOT )?%{kernel}(\.\d+)? = ", line)]
+
+
+@pytest.mark.parametrize("cell", sorted(ATTENTION))
+def test_flash_attention_compiles_for_v5e(cell, one_chip):
+    """Forward and backward of the fused causal attention at a cell's
+    heads and head size, one sequence of 4096 tokens: three Mosaic
+    kernels."""
+    from repro.kernels.flash_attention import causal_attention
+
+    hq, hkv, hd, scale = ATTENTION[cell]
+
+    def sds(heads):
+        return _sds(one_chip, (1, heads, TOKENS, hd), jnp.bfloat16)
+
+    def fwd_bwd(q, k, v, do):
+        out, vjp = jax.vjp(lambda *a: causal_attention(
+            *a, scale=scale, interpret=False), q, k, v)
+        return out, vjp(do)
+
+    text = jax.jit(fwd_bwd).lower(sds(hq), sds(hkv), sds(hkv),
+                                  sds(hq)).compile().as_text()
+    for kernel in ATTENTION_KERNELS:
+        calls = _kernel_calls(text, kernel)
+        assert calls and all("tpu_custom_call" in c for c in calls), kernel
+
+
 def test_smoke_train_step_compiles_for_v5e(topo, monkeypatch):
-    """The whole chip-smoke step at full width: Mosaic kernels inside, and
+    """The whole chip-smoke step at full width: Mosaic kernels inside, the
+    fused attention's among them under the ``model.attn`` scope, and
     arguments plus temporaries within one chip's 16 GiB."""
     from repro.configs import get_config
     from repro.kernels import ops
@@ -151,7 +191,13 @@ def test_smoke_train_step_compiles_for_v5e(topo, monkeypatch):
     compiled = runtime.make_train_step(optimizer).lower(
         runtime.param_shapes(), optimizer.state_shapes(runtime), step,
         batch).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for kernel in ATTENTION_KERNELS:
+        calls = _kernel_calls(text, kernel)
+        assert calls, kernel
+        assert all("tpu_custom_call" in c and "model.attn" in c
+                   for c in calls), kernel
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used <= 16 * GIB, (mem.argument_size_in_bytes,
